@@ -16,6 +16,7 @@ from __future__ import annotations
 import argparse
 import traceback
 
+from repro.core.compile_cache import use_compile_cache
 from repro.perf.measure import now
 
 from benchmarks import (
@@ -49,6 +50,7 @@ BENCHMARKS = [
 
 
 def main() -> None:
+    use_compile_cache()
     names = [n for n, _ in BENCHMARKS]
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, metavar="NAME[,NAME...]",

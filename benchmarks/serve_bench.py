@@ -87,6 +87,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.configs import reduced_config
+from repro.core.compile_cache import use_compile_cache
 from repro.launch.mesh import AxisType, make_mesh
 from repro.models import build_model
 from repro.models.decode_state import stub_context
@@ -937,7 +938,8 @@ def run(measure: bool = True,
     return rows
 
 
-if __name__ == "__main__":
+def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--families", default=None,
                     help="'all' or comma list of "
@@ -974,3 +976,7 @@ if __name__ == "__main__":
         prefix_only=args.prefix_only, sharded=args.sharded,
         sp_kv=args.sp_kv, retune=args.retune, open_loop=args.open_loop,
         speculative=args.speculative)
+
+
+if __name__ == "__main__":
+    main()

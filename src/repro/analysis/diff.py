@@ -9,8 +9,8 @@ into a typed, waivable :class:`~repro.analysis.findings.Finding` on the
 ``<diff:<target>>`` pseudo-path:
 
 ``new-gather`` (error)
-    gather/scatter ops appeared in — or grew on — a program whose
-    baseline pinned fewer.  The headline drift: the paged decode path
+    gather or scatter ops appeared in — or grew on — a program whose
+    baseline pinned fewer (each kind is compared on its own).  The headline drift: the paged decode path
     is gather-free by construction (PR 6) and must stay that way.
 
 ``flops-inflation`` (warning)
@@ -122,15 +122,16 @@ def diff_fingerprint(name: str, base: Dict[str, Any],
     """Every drift finding for one pinned program."""
     findings: List[Finding] = []
 
-    b_gather = int(base.get("gather_ops", 0))
-    l_gather = int(live.get("gather_ops", 0))
-    if l_gather > b_gather:
-        findings.append(_finding(
-            "new-gather", name,
-            f"program {name}: {l_gather} gather/scatter op(s) vs "
-            f"{b_gather} in the baseline — a mispriced access pattern "
-            "crept back into a pinned program",
-            context={"baseline": b_gather, "live": l_gather}))
+    for kind in ("gather", "scatter"):
+        b_n = int(base.get(f"{kind}_ops", 0))
+        l_n = int(live.get(f"{kind}_ops", 0))
+        if l_n > b_n:
+            findings.append(_finding(
+                "new-gather", name,
+                f"program {name}: {l_n} {kind} op(s) vs {b_n} in the "
+                "baseline — a mispriced access pattern crept back into "
+                "a pinned program",
+                context={"kind": kind, "baseline": b_n, "live": l_n}))
 
     b_cnt = base.get("counters", {}) or {}
     l_cnt = live.get("counters", {}) or {}

@@ -76,6 +76,7 @@ def fingerprint_report(rep: TraceReport, *,
                                 sorted(rep.instruction_classes.items())},
         "total_ops": int(rep.total_ops),
         "gather_ops": int(rep.gather_ops),
+        "scatter_ops": int(rep.scatter_ops),
         "select_frac": round(rep.select_frac, 4),
         "while_bodies": int(rep.while_bodies),
         "f32_instr_frac": round(

@@ -21,9 +21,8 @@ Rules (ids are stable — waivers and tests key on them):
     direct ``jax.sharding.Mesh(...)`` / ``jax.make_mesh`` construction,
     ``shard_map`` access (``jax.shard_map`` or
     ``jax.experimental.shard_map``), or ``.cost_analysis()`` method
-    calls outside ``core/compat.py`` + ``launch/mesh.py``.  The repo
-    supports jax>=0.4.37 only because every cross-version seam is
-    normalized in those two modules.
+    calls outside ``core/compat.py`` + ``launch/mesh.py``, so a JAX
+    upgrade that moves one of these APIs is fixed in those two modules.
 
 ``results-writer-bypass`` (error)
     raw ``json.dump`` / ``json.dumps`` in ``benchmarks/`` outside
@@ -304,8 +303,7 @@ def lint_source(src: str, rel: str) -> List[Finding]:
         elif kind == "shard_map" and not compat_ok:
             findings.append(Finding(
                 "compat-shim-bypass", "error", rel, node.lineno,
-                f"{what} — use repro.core.compat.shard_map (jax 0.4.x vs "
-                "0.6+ relocation/kwarg rename)"))
+                f"{what} — use repro.core.compat.shard_map"))
 
     for node in _outermost_attributes(tree):
         d = _dotted(node, mod_aliases)
@@ -336,15 +334,13 @@ def lint_source(src: str, rel: str) -> List[Finding]:
                 findings.append(Finding(
                     "compat-shim-bypass", "error", rel, node.lineno,
                     f"direct mesh construction ({d or origin}) — build "
-                    "meshes via repro.launch.mesh.make_mesh (axis_types "
-                    "compat on jax 0.4.x)"))
+                    "meshes via repro.launch.mesh.make_mesh"))
             elif isinstance(func, ast.Attribute) \
                     and func.attr == "cost_analysis":
                 findings.append(Finding(
                     "compat-shim-bypass", "error", rel, node.lineno,
-                    ".cost_analysis() returns a per-module list on jax "
-                    "0.4.x and a dict/None later — use "
-                    "repro.core.compat.cost_dict"))
+                    ".cost_analysis() may return None or a per-module "
+                    "list — use repro.core.compat.cost_dict"))
         if not results_ok and (d in ("json.dump", "json.dumps")
                                or origin in ("json.dump", "json.dumps")):
             findings.append(Finding(

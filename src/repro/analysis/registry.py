@@ -29,7 +29,7 @@ from repro.analysis.lint import SOURCE_RULES, Rule
 #: (which imports this table so the ids/docs exist in exactly one place)
 TRACE_RULES: Dict[str, Rule] = {r.rule: r for r in (
     Rule("hot-gather", "warning",
-         "gather/scatter access in the compiled module"),
+         "gather access in the compiled module"),
     Rule("predication-density", "warning",
          "select density above threshold (predication-heavy lowering)"),
     Rule("scan-counter-blindness", "error",

@@ -14,10 +14,13 @@ text, ``repro.core.compat.cost_dict`` reads the cost channels) and
 Rules (ids are stable):
 
 ``hot-gather`` (warning)
-    gather/scatter ops in the compiled module — the access pattern the
-    paper's Fig-2 shows cost models misprice hardest.  On a decode hot
-    path this is usually the paged-KV gather; the finding makes the
-    benchmark artifact record that its hot path carries it.
+    gather ops in the compiled module — the access pattern the paper's
+    Fig-2 shows cost models misprice hardest.  On a decode hot path
+    this is usually the paged-KV gather; the finding makes the
+    benchmark artifact record that its hot path carries it.  Scatters
+    (the per-row KV-cache and output writes every decode step makes)
+    are counted apart as ``scatter_ops``: the drift gate pins them, the
+    lint does not flag them.
 
 ``predication-density`` (warning)
     ``select`` density above threshold — predication-heavy lowering
@@ -101,12 +104,18 @@ class TraceReport:
     @property
     def gather_ops(self) -> int:
         return sum(n for op, n in self.op_histogram.items()
-                   if op.startswith(("gather", "scatter")))
+                   if op.startswith("gather"))
+
+    @property
+    def scatter_ops(self) -> int:
+        return sum(n for op, n in self.op_histogram.items()
+                   if op.startswith("scatter"))
 
     def summary(self) -> Dict[str, Any]:
         return {"label": self.label, "total_ops": self.total_ops,
                 "while_bodies": self.while_bodies,
                 "gather_ops": self.gather_ops,
+                "scatter_ops": self.scatter_ops,
                 "select_frac": round(self.select_frac, 4),
                 "f32_instr_frac": round(
                     self.f32_instrs / max(1, self.typed_instrs), 4),
@@ -117,7 +126,8 @@ class TraceReport:
 def _jaxpr_primitives(closed) -> Tuple[str, ...]:
     """All primitive names in a (closed) jaxpr, recursing into sub-jaxprs
     (scan/while/cond bodies, pjit calls)."""
-    core = jax.core
+    from jax.extend import core
+
     seen: set = set()
 
     def walk(jxp) -> None:
@@ -196,7 +206,7 @@ def lint_trace(report: TraceReport, *,
     if n_gather >= gather_threshold:
         findings.append(Finding(
             "hot-gather", "warning", path, 0,
-            f"{n_gather} gather/scatter op(s) in the compiled module — "
+            f"{n_gather} gather op(s) in the compiled module — "
             "the strided/gather access pattern compiler cost models "
             "misprice hardest (paper Fig-2); expected for paged-KV "
             "decode, but the artifact should say so",

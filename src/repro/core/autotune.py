@@ -23,7 +23,8 @@ import pathlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.core.costmodel import TPU_V5E, HWSpec
-from repro.kernels.common import MXU, SUBLANE, VALID_MULTIPLIERS
+from repro.kernels.common import (MXU, SCOPED_VMEM_BYTES, SUBLANE,
+                                  VALID_MULTIPLIERS)
 from repro.perf.measure import measure_group
 
 # On-disk best-config cache: a schema-valid perf Report (rows =
@@ -150,6 +151,7 @@ def cached_best_config(key: str,
 def tune_paged_attention(*, n_slots: int, max_len: int, page_size: int,
                          n_kv_heads: int, n_q_heads: int, head_dim: int,
                          dtype: str, impl: Optional[str] = None,
+                         max_q_len: int = 1,
                          reps: int = 3, retune: bool = False,
                          cache_path: Optional[pathlib.Path] = None
                          ) -> Dict[str, Any]:
@@ -157,20 +159,27 @@ def tune_paged_attention(*, n_slots: int, max_len: int, page_size: int,
     flash-decode kernel at the engine's decode shapes.
 
     Keyed on (head_dim, n_kv_heads, page_size, dtype) plus
-    pages_per_seq — engines with different cache lengths have different
-    candidate grids, so they cache separately rather than thrash one
-    row.  Candidates are full-cache decode calls timed as interleaved
-    contenders (``measured_sweep``); impl/backend resolution matches
-    what the engine will actually run.
+    pages_per_seq, the impl and the device kind — engines with
+    different cache lengths have different candidate grids, and a row
+    measured on one device never steers another.  Only candidates whose
+    kernel VMEM footprint (``kernel.vmem_bytes`` at the widest query
+    block the engine runs, ``max_q_len`` columns) fits Mosaic's scoped
+    VMEM limit (``SCOPED_VMEM_BYTES``) are swept.  Candidates are
+    full-cache decode calls timed as interleaved contenders
+    (``measured_sweep``); impl/backend resolution matches what the
+    engine will actually run.
     """
     import jax
     import jax.numpy as jnp
+    from repro.kernels.paged_attention import kernel as pa_kernel
     from repro.kernels.paged_attention import ops as pa_ops
 
     pps = max_len // page_size
+    group = max(n_q_heads // n_kv_heads, 1)
+    impl = pa_ops.resolve_impl(impl)
     key = (f"paged_attention/hd{head_dim}/nkv{n_kv_heads}"
-           f"/g{max(n_q_heads // n_kv_heads, 1)}/page{page_size}"
-           f"/pps{pps}/{dtype}/{pa_ops.resolve_impl(impl)}")
+           f"/g{group}/page{page_size}/pps{pps}/{dtype}/{impl}"
+           f"/{jax.devices()[0].device_kind}")
     jdt = jnp.dtype(dtype)
     k0 = jax.random.key(0)
     q = jax.random.normal(
@@ -186,6 +195,15 @@ def tune_paged_attention(*, n_slots: int, max_len: int, page_size: int,
     valid = jnp.full((n_slots,), max_len, jnp.int32)
     bps = sorted({bp for bp in (1, 2, 4, 8, pps)
                   if 1 <= bp <= pps and pps % bp == 0})
+    footprint = {bp: pa_kernel.vmem_bytes(
+        block_pages=bp, page_size=page_size, n_kv_heads=n_kv_heads,
+        head_dim=head_dim, rows=group * max_q_len,
+        dtype_bytes=jdt.itemsize) for bp in bps}
+    bps = [bp for bp in bps if footprint[bp] <= SCOPED_VMEM_BYTES]
+    if not bps:
+        raise ValueError(
+            f"no block_pages candidate fits {SCOPED_VMEM_BYTES} B of VMEM: "
+            f"footprints {footprint}")
     candidates = {
         f"bp{bp}": (functools.partial(
             pa_ops.paged_attention, page_size=page_size, block_pages=bp,
@@ -193,7 +211,7 @@ def tune_paged_attention(*, n_slots: int, max_len: int, page_size: int,
         for bp in bps}
     res = cached_best_config(key, candidates, reps=reps, retune=retune,
                              cache_path=cache_path)
-    return {"key": res["key"], "best": res["best"],
+    return {"key": res["key"], "impl": impl, "best": res["best"],
             "block_pages": int(res["best"][2:]),
             "medians_s": res["medians_s"], "source": res["source"]}
 

@@ -1,33 +1,16 @@
-"""JAX version-compatibility shims.
+"""The one spelling of the JAX seams the repo calls (jax 0.9.0, see
+requirements-dev.txt); mesh construction lives in ``repro.launch.mesh``.
+The source lint's ``compat-shim-bypass`` rule keeps every call site here:
 
-The repo pins a JAX floor of 0.4.37 (see requirements-dev.txt) but is
-written against newer API shapes.  Every cross-version seam is normalized
-here (or, for mesh construction, in ``repro.launch.mesh``) so the rest of
-the codebase uses one spelling:
-
-  cost_dict   ``Compiled.cost_analysis()`` returns a per-module *list* of
-              dicts on 0.4.x and a plain dict (or None) on newer releases.
-  shard_map   lives at ``jax.experimental.shard_map`` on 0.4.x (kwarg
-              ``check_rep``) and at ``jax.shard_map`` (kwarg ``check_vma``)
-              afterwards.
-
-Supported range: jax >= 0.4.37 (older releases lack ``jax.make_mesh``).
+  cost_dict   ``Compiled.cost_analysis()`` as one flat dict (``{}`` when
+              the backend reports nothing).
+  shard_map   ``jax.shard_map`` with the checking switch named ``check``.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Dict
 
 import jax
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
 
 
 def cost_dict(compiled) -> Dict[str, Any]:
@@ -45,9 +28,7 @@ def cost_dict(compiled) -> Dict[str, Any]:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """``jax.shard_map`` across the 0.4.x -> 0.6+ relocation/rename.
-
-    ``check`` keeps upstream's checking default (replication/VMA
-    validation on); callers that need it off opt out explicitly."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
+    """``jax.shard_map``; ``check`` keeps upstream's checking default
+    (VMA validation on) — callers that need it off opt out explicitly."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -16,6 +16,9 @@ import jax
 LANE = 128        # TPU vector lane width (last-dim alignment)
 SUBLANE = 8       # f32 sublane count (second-minor alignment)
 MXU = 128         # systolic array dim
+# Mosaic's default scoped-VMEM limit per kernel on v4 / v5e (v6e: 32 MiB);
+# a kernel whose blocks need more is refused at compile time
+SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
 VALID_MULTIPLIERS = (1, 2, 4, 8)
 

@@ -48,10 +48,9 @@ def conv2d_same(x, w, *, block_h=8, interpret=True):
         grid=grid,
         in_specs=[
             # haloed input block: bh + kh - 1 rows starting at element i*bh
-            # (unblocked = element-indexed dims -> overlapping halo reads)
-            pl.BlockSpec((1, bh + kh - 1, W + kw - 1, Cin),
-                         lambda n, i: (n, i * bh, 0, 0),
-                         indexing_mode=pl.unblocked),
+            # (an element-indexed row dim -> overlapping halo reads)
+            pl.BlockSpec((1, pl.Element(bh + kh - 1), W + kw - 1, Cin),
+                         lambda n, i: (n, i * bh, 0, 0)),
             pl.BlockSpec((kh, kw, Cin, Cout), lambda n, i: (0, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bh, W, Cout), lambda n, i: (n, i, 0, 0)),

@@ -19,8 +19,9 @@ materialization.
   ``combine_partials``.
 
 ``block_pages`` (pages per tile) is autotuned per
-(head_dim, n_kv_heads, page_size, dtype) via
-``core.autotune.tune_paged_attention`` with an on-disk cache at
+(head_dim, n_kv_heads, page_size, dtype, impl, device kind) over the
+tiles whose VMEM footprint fits (``kernel.vmem_bytes``) via
+``core.autotune.tune_paged_attention``, with an on-disk cache at
 ``benchmarks/results/autotune_cache.json``.
 """
 from repro.kernels.paged_attention import ref

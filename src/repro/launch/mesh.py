@@ -6,46 +6,28 @@ importing this module touches no jax device state.  The single-pod mesh is
 (2 pods = 512 chips).  Data parallelism maps to ("pod", "data"), tensor/
 expert parallelism to "model" (see repro.parallel).
 
-``make_mesh`` / ``AxisType`` are the version-compat entry points (floor:
-jax 0.4.37, where ``jax.sharding.AxisType`` and the ``axis_types=`` kwarg
-of ``jax.make_mesh`` do not exist yet).  Every mesh in the repo is built
-through them; on older jax the axis types are simply dropped, which is
-semantically the 0.4.x default (everything is Auto).
+``make_mesh`` (with ``AxisType`` re-exported) is the one mesh entry
+point: every mesh in the repo is built through it, which the source
+lint's ``compat-shim-bypass`` rule enforces.
 """
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: no explicit-sharding axis types yet
-    class AxisType:  # noqa: D401 - enum-shaped placeholder
-        """Fallback for ``jax.sharding.AxisType`` on jax 0.4.x."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-_HAS_AXIS_TYPES = "axis_types" in inspect.signature(jax.make_mesh).parameters
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types: Optional[Sequence] = None,
               devices=None) -> Mesh:
-    """``jax.make_mesh`` that tolerates ``axis_types`` on jax 0.4.x.
-
-    On versions whose ``make_mesh`` lacks the kwarg the requested types are
-    dropped: 0.4.x meshes are implicitly all-Auto, so dropping ``Auto``
-    types (the only kind this repo requests) is behavior-preserving.
-    """
+    """``jax.make_mesh`` over ``devices`` (default: all) with optional
+    ``axis_types``."""
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if axis_types is not None and _HAS_AXIS_TYPES:
+    if axis_types is not None:
         kwargs["axis_types"] = tuple(axis_types)
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, reduced_config
+from repro.core.compile_cache import use_compile_cache
 from repro.launch.mesh import parse_mesh
 from repro.models import build_model
 from repro.models.decode_state import stub_context
@@ -43,6 +44,7 @@ from repro.serve import ContinuousBatchingEngine, StaticBatchEngine
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=ARCH_IDS)
     ap.add_argument("--reduced", action="store_true")

@@ -486,21 +486,49 @@ def _paged_attention_with_cache(q, k, v, ps, *, positions, kv_valid_len,
     a page pool (B*pages, page_size, NKV, H) — a reshape, not a gather —
     and kernels/paged_attention streams pages by page-id with the ragged
     mask folded in.  Clears the trace-lint ``hot-gather`` finding the
-    dense ``_full_attention_with_cache`` path triggers."""
-    from repro.kernels.paged_attention import ops as pa_ops
+    dense ``_full_attention_with_cache`` path triggers.
 
-    B, S_cache, NKV, H = k.shape
-    pps = S_cache // ps.page_size
-    k_pages = k.reshape(B * pps, ps.page_size, NKV, H)
-    v_pages = v.reshape(B * pps, ps.page_size, NKV, H)
-    page_idx = ps.page_idx
-    if page_idx is None:
-        # row-local identity map (engine prefill rows run batch=1)
-        page_idx = jnp.arange(B * pps, dtype=jnp.int32).reshape(B, pps)
-    return pa_ops.paged_attention(
-        q, k_pages, v_pages, page_idx, positions, kv_valid_len,
-        page_size=ps.page_size, softcap=softcap,
-        block_pages=ps.block_pages, impl=ps.impl)
+    Under a sharding context the kernel runs inside ``shard_map`` (Mosaic
+    kernels cannot be partitioned automatically): slots split over the
+    cache's "batch" axes and heads over its "kv_heads" axis, and each
+    shard walks its own rows with the row-local identity page map — the
+    engine's page layout is the identity, so global row b's pages are
+    local row b's pages in the shard that holds b."""
+    from repro.kernels.paged_attention import ops as pa_ops
+    from repro.parallel import axes as paxes
+
+    def attend(q, k, v, positions, kv_valid_len, page_idx):
+        B, S_cache, NKV, H = k.shape
+        pps = S_cache // ps.page_size
+        k_pages = k.reshape(B * pps, ps.page_size, NKV, H)
+        v_pages = v.reshape(B * pps, ps.page_size, NKV, H)
+        if page_idx is None:
+            # row-local identity map (engine prefill rows run batch=1)
+            page_idx = jnp.arange(B * pps, dtype=jnp.int32).reshape(B, pps)
+        return pa_ops.paged_attention(
+            q, k_pages, v_pages, page_idx, positions, kv_valid_len,
+            page_size=ps.page_size, softcap=softcap,
+            block_pages=ps.block_pages, impl=ps.impl)
+
+    if not paxes.active():
+        return attend(q, k, v, positions, kv_valid_len, ps.page_idx)
+
+    from jax.sharding import PartitionSpec as P
+    from repro.core.compat import shard_map
+
+    spec = paxes.resolve_spec(("batch", None, "kv_heads", None), k.shape,
+                              record=False)
+    spec = tuple(spec) + (None,) * (4 - len(spec))
+    bax, hax = spec[0], spec[2]
+    # query heads are KV-head-major (head n*G + g), so splitting them over
+    # the KV-head axis keeps every group on its KV head's shard
+    heads = P(bax, None, hax, None)
+    return shard_map(
+        lambda q, k, v, pos, kv: attend(q, k, v, pos, kv, None),
+        mesh=paxes.current_mesh(),
+        in_specs=(heads, heads, heads, P(bax, None), P(bax)),
+        out_specs=heads, check=False,
+    )(q, k, v, positions, kv_valid_len)
 
 
 def _attn_decode_spkv(params, q, k, v, cfg, *, positions, cache, axis,

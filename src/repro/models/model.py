@@ -271,11 +271,17 @@ class LM:
         logits = layers.unembed(x, emb)
         logits = constrain(logits, "batch", None, "vocab")
 
+        logits = logits.astype(jnp.float32)
+        if cfg.padded_vocab != cfg.vocab_size:
+            # the table's padding rows are not tokens: never predict one
+            logits = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab_size,
+                               logits, -1e30)
+
         new_cache = None
         if cache is not None:
             key = "layers" if "layers" in cache else "periods"
             new_cache = {key: new_stacked}
-        return logits.astype(jnp.float32), new_cache, aux
+        return logits, new_cache, aux
 
     # ------------------------------------------------------------------
     def encode_audio(self, params: Params, frames: jax.Array

@@ -613,7 +613,9 @@ class ContinuousBatchingEngine:
             n_slots=self.n_slots, max_len=self.max_len,
             page_size=self.kv.page_size, n_kv_heads=cfg.n_kv_heads,
             n_q_heads=cfg.n_heads, head_dim=cfg.resolved_head_dim,
-            dtype=cfg.compute_dtype, retune=retune)
+            dtype=cfg.compute_dtype,
+            max_q_len=max(self.sched.prefill_chunk, self.spec_k + 1),
+            retune=retune)
         self._paged_block_pages = int(info["block_pages"])
         return info
 

@@ -12,7 +12,8 @@ import pytest
 
 #: modules whose engines run under the shadow checker (the tier1 serve
 #: surface: continuous engine, families parity, frontend, prefix cache,
-#: sharded layouts, and the speculative-decode driver)
+#: sharded layouts, the speculative-decode driver, and the chip smoke
+#: test's CPU rehearsal)
 SERVE_TEST_MODULES = (
     "test_serve",
     "test_serve_families",
@@ -21,6 +22,7 @@ SERVE_TEST_MODULES = (
     "test_serve_sharded",
     "test_serve_spec",
     "test_spkv_decode",
+    "test_chip_smoke",
 )
 
 

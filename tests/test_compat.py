@@ -1,8 +1,5 @@
-"""JAX-compat shims: cost_analysis normalization + mesh construction.
-
-These are the regression tests for the jax-0.4.37 breakage (list-valued
-``cost_analysis()``, missing ``jax.sharding.AxisType`` / ``axis_types=``,
-relocated ``shard_map``)."""
+"""The JAX seams in repro.core.compat + repro.launch.mesh:
+cost_analysis normalization, mesh construction, shard_map."""
 import jax
 import jax.numpy as jnp
 import pytest

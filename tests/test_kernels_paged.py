@@ -223,3 +223,55 @@ def test_paged_engine_matches_xla_token_for_token(family, arch):
         np.testing.assert_array_equal(
             outs[True][i], outs[False][i],
             err_msg=f"{family}: paged/xla token divergence (request {i})")
+
+
+# ---------------------------------------------------------------------------
+# autotune: keyed by device kind, bounded by the kernel's VMEM footprint
+# ---------------------------------------------------------------------------
+_TUNE = dict(n_slots=2, max_len=64, page_size=8, n_kv_heads=1, n_q_heads=4,
+             head_dim=16, dtype="float32", reps=1)
+
+
+def test_autotune_row_of_another_device_never_steers(tmp_path):
+    import json
+
+    from repro.core import autotune
+
+    path = tmp_path / "tune.json"
+    info = autotune.tune_paged_attention(cache_path=path, **_TUNE)
+    kind = jax.devices()[0].device_kind
+    assert info["source"] == "measured"
+    assert info["key"].endswith(f"/{info['impl']}/{kind}")
+    assert set(info["medians_s"]) == {"bp1", "bp2", "bp4", "bp8"}
+    # the same row, relabelled as measured on a chip: it must not be read
+    payload = json.loads(path.read_text())
+    (row,) = payload["rows"]
+    row["key"] = row["key"][:-len(kind)] + "TPU v5 lite"
+    path.write_text(json.dumps(payload))
+    assert autotune.tune_paged_attention(
+        cache_path=path, **_TUNE)["source"] == "measured"
+    assert autotune.tune_paged_attention(
+        cache_path=path, **_TUNE)["source"] == "cache"
+
+
+def test_autotune_sweeps_only_tiles_that_fit_vmem(tmp_path, monkeypatch):
+    from repro.core import autotune
+    from repro.kernels.paged_attention import kernel as pa_kernel
+
+    def footprint(bp):
+        # 8 query columns (a prefill chunk) x group 4 rows per KV head
+        return pa_kernel.vmem_bytes(block_pages=bp, page_size=8,
+                                    n_kv_heads=1, head_dim=16, rows=4 * 8,
+                                    dtype_bytes=4)
+
+    assert footprint(1) < footprint(2) < footprint(4)
+    # a chip whose scoped VMEM holds exactly the 2-page tile
+    monkeypatch.setattr(autotune, "SCOPED_VMEM_BYTES", footprint(2))
+    info = autotune.tune_paged_attention(
+        cache_path=tmp_path / "tune.json", max_q_len=8, **_TUNE)
+    assert set(info["medians_s"]) == {"bp1", "bp2"}
+    assert info["block_pages"] in (1, 2)
+    monkeypatch.setattr(autotune, "SCOPED_VMEM_BYTES", footprint(1) - 1)
+    with pytest.raises(ValueError, match="no block_pages candidate fits"):
+        autotune.tune_paged_attention(
+            cache_path=tmp_path / "tune.json", max_q_len=8, **_TUNE)

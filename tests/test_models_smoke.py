@@ -88,3 +88,19 @@ def test_param_count_plausible():
     assert 5.5e9 < a2 < 8.5e9, a2
     t3, _ = get_config("mamba2-780m").param_counts()
     assert 0.6e9 < t3 < 0.95e9, t3
+
+
+def test_padded_vocab_rows_are_never_predicted():
+    # published vocabularies pad the table (granite: 49155 -> 49408
+    # rows); the padding rows are no token, so their logits are masked
+    cfg = reduced_config("granite-3-2b", vocab_size=500)
+    assert cfg.padded_vocab == 512
+    model = build_model(cfg)
+    params = model.init_params(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 8), 0, cfg.vocab_size)
+    positions = jnp.broadcast_to(jnp.arange(8), (2, 8))
+    logits, _, _ = model.forward(params, tokens, positions)
+    assert logits.shape[-1] == 512
+    assert bool(jnp.all(logits[..., cfg.vocab_size:] <= -1e29))
+    assert bool(jnp.all(jnp.isfinite(logits[..., :cfg.vocab_size])))
+    assert int(jnp.argmax(logits, -1).max()) < cfg.vocab_size
