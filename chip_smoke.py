@@ -11,14 +11,16 @@ One chip runs three phases in order, in this one process:
     found otherwise);
 (b) kernel — ``kernels/paged_attention`` through ``impl="pallas"`` against
     the float32 gather oracle (``ref.py``) at the served shapes, decode
-    (Sq=1) and prefill-chunk (Sq=8) widths, ragged ``kv_valid`` with an
+    (Sq=1) and prefill-chunk (Sq=8) widths, at every ``block_pages``
+    tile the engine's autotune may pick, ragged ``kv_valid`` with an
     empty row, a one-token row and a partial last page;
 (c) serve — granite-3-2b from ``get_config`` (bf16, 40 layers, random
-    weights from ``--seed``) in a ``ContinuousBatchingEngine`` of 8
+    weights from seed 0) in a ``ContinuousBatchingEngine`` of 8
     slots x 1024 tokens, 16 requests of 64-512 prompt tokens and 32 new
     tokens each (two sampled at temperature 0.7), drained with
     ``engine.run()``; the compiled decode and prefill programs must hold
-    the Pallas kernel (``tpu_custom_call``).
+    the Pallas kernel (``tpu_custom_call``), and the autotune's tile must
+    be one that phase (b) checked.
 
 ``--four-chips`` serves granite-3-2b in float32 on a 2x2 mesh (slots over
 "data", heads over "model") and on one chip, and requires the same
@@ -44,6 +46,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.core.autotune import paged_block_candidates  # noqa: E402
 from repro.core.compile_cache import use_compile_cache  # noqa: E402
 from repro.kernels.paged_attention import ops as pa_ops  # noqa: E402
 from repro.kernels.paged_attention import ref as pa_ref  # noqa: E402
@@ -52,6 +55,7 @@ from repro.perf.measure import now  # noqa: E402
 from repro.serve import ContinuousBatchingEngine  # noqa: E402
 
 ARCH = "granite-3-2b"
+SEED = 0                   # random weights, requests and kernel inputs
 N_SLOTS, PAGE, MAX_LEN, PREFILL_CHUNK = 8, 16, 1024, 8
 N_REQUESTS, PROMPT_LENS, NEW_TOKENS = 16, (64, 512), 32
 SAMPLED = (3, 11)          # the requests served at temperature 0.7
@@ -74,13 +78,19 @@ def check_device() -> jax.Device:
 def kernel_phase(*, batch: int, n_q_heads: int, n_kv_heads: int,
                  head_dim: int, page_size: int, max_len: int,
                  q_lens=(1, PREFILL_CHUNK), dtype=jnp.bfloat16,
-                 seed: int = 0) -> float:
+                 seed: int = SEED):
     """Phase (b): the Pallas paged kernel against the fp32 oracle.
 
-    Block 1 streams a permuted page map; block 8 (the engine's identity
-    layout, contiguous 8-page chunks) the identity map.  Returns the
-    worst max-abs error; raises when it exceeds ``KERNEL_TOL``."""
+    One-page blocks stream a permuted page map; every tile the engine's
+    autotune may pick (``paged_block_candidates`` at the widest query
+    block here) streams the engine's identity map.  Returns the worst
+    max-abs error and the tiles checked; raises when an error exceeds
+    ``KERNEL_TOL``."""
     pps = max_len // page_size
+    tiles = paged_block_candidates(
+        pps=pps, page_size=page_size, n_kv_heads=n_kv_heads,
+        head_dim=head_dim, rows=n_q_heads // n_kv_heads * max(q_lens),
+        dtype_bytes=jnp.dtype(dtype).itemsize)
     key = jax.random.key(seed)
     kq, kk, kv, kp = jax.random.split(key, 4)
     pool = (batch * pps, page_size, n_kv_heads, head_dim)
@@ -101,7 +111,8 @@ def kernel_phase(*, batch: int, n_q_heads: int, n_kv_heads: int,
                               jnp.float32).astype(dtype)
         pos = (np.maximum(valid - sq, 0)[:, None]
                + np.arange(sq, dtype=np.int32)[None])
-        for bp, page_idx in ((1, permuted), (8, identity)):
+        for bp, kind, page_idx in [(1, "permuted", permuted)] + [
+                (t, "identity", identity) for t in tiles]:
             args = (q, k_pages, v_pages, page_idx, jnp.asarray(pos),
                     jnp.asarray(valid))
             out = pa_ops.paged_attention(*args, page_size=page_size,
@@ -109,18 +120,18 @@ def kernel_phase(*, batch: int, n_q_heads: int, n_kv_heads: int,
             want = pa_ref.paged_attention(
                 q.astype(jnp.float32), *args[1:])
             err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)))
-            print(f"[kernel] Sq={sq} block_pages={bp}: max|err|={err!r} "
-                  f"(bound {KERNEL_TOL})")
+            print(f"[kernel] Sq={sq} block_pages={bp} {kind} map: "
+                  f"max|err|={err!r} (bound {KERNEL_TOL})")
             if not np.isfinite(err) or err > KERNEL_TOL:
                 raise AssertionError(
-                    f"paged kernel Sq={sq} block_pages={bp}: max|err| "
-                    f"{err} exceeds {KERNEL_TOL}")
+                    f"paged kernel Sq={sq} block_pages={bp} {kind} map: "
+                    f"max|err| {err} exceeds {KERNEL_TOL}")
             worst = max(worst, err)
-    return worst
+    return worst, tiles
 
 
 def make_requests(vocab_size: int, *, n: int = N_REQUESTS,
-                  prompt_lens=PROMPT_LENS, seed: int = 0):
+                  prompt_lens=PROMPT_LENS, seed: int = SEED):
     """``n`` seeded (prompt, temperature) pairs."""
     rng = np.random.default_rng(seed)
     out = []
@@ -131,8 +142,9 @@ def make_requests(vocab_size: int, *, n: int = N_REQUESTS,
     return out
 
 
-def build_engine(cfg, *, seed: int = 0, mesh=None, n_slots: int = N_SLOTS,
-                 max_len: int = MAX_LEN, page_size: int = PAGE,
+def build_engine(cfg, *, seed: int = SEED, mesh=None,
+                 n_slots: int = N_SLOTS, max_len: int = MAX_LEN,
+                 page_size: int = PAGE,
                  prefill_chunk: int = PREFILL_CHUNK):
     """granite (or any dense ``cfg``) with seeded random weights, made on
     the device in one jitted init — on ``mesh`` directly in its layout."""
@@ -170,7 +182,7 @@ def serve(engine, requests, new_tokens: int = NEW_TOKENS):
     return {rid: np.asarray(out[rid]) for rid in rids}, wall
 
 
-def serve_phase(cfg, *, seed: int = 0, n_requests: int = N_REQUESTS,
+def serve_phase(cfg, *, seed: int = SEED, n_requests: int = N_REQUESTS,
                 prompt_lens=PROMPT_LENS, new_tokens: int = NEW_TOKENS,
                 **engine_kw):
     """Phase (c): build the engine, serve the seeded requests, drain.
@@ -200,7 +212,7 @@ def compiled_text(engine) -> dict:
             .as_text()}
 
 
-def four_chip_phase(cfg, *, mesh_spec: str = "2x2", seed: int = 0,
+def four_chip_phase(cfg, *, mesh_spec: str = "2x2", seed: int = SEED,
                     n_requests: int = N_REQUESTS, prompt_lens=PROMPT_LENS,
                     new_tokens: int = NEW_TOKENS, **engine_kw) -> None:
     """The temperature-0 requests on one device, then on ``mesh_spec``:
@@ -255,22 +267,25 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the float32 2x2-mesh vs one-chip check")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     dev = check_device()
 
     if args.four_chips:
         four_chip_phase(get_config(ARCH, param_dtype="float32",
-                                   compute_dtype="float32"), seed=args.seed)
+                                   compute_dtype="float32"))
     else:
         cfg = get_config(ARCH)
-        kernel_phase(batch=N_SLOTS, n_q_heads=cfg.n_heads,
-                     n_kv_heads=cfg.n_kv_heads,
-                     head_dim=cfg.resolved_head_dim, page_size=PAGE,
-                     max_len=MAX_LEN, seed=args.seed)
-        engine, _ = serve_phase(cfg, seed=args.seed)
-        if engine.paged_meta["impl"] != "pallas":
-            raise AssertionError(f"paged impl {engine.paged_meta['impl']}")
+        _, tiles = kernel_phase(
+            batch=N_SLOTS, n_q_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            head_dim=cfg.resolved_head_dim, page_size=PAGE, max_len=MAX_LEN)
+        engine, _ = serve_phase(cfg)
+        pm = engine.paged_meta
+        if pm["impl"] != "pallas":
+            raise AssertionError(f"paged impl {pm['impl']}")
+        if pm["block_pages"] not in tiles:
+            raise AssertionError(f"autotune picked block_pages="
+                                 f"{pm['block_pages']}, not among the "
+                                 f"tiles checked {tiles}")
         for name, text in compiled_text(engine).items():
             if "tpu_custom_call" not in text:
                 raise AssertionError(f"compiled {name} program holds no "

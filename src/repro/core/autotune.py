@@ -148,6 +148,29 @@ def cached_best_config(key: str,
     return {**row, "source": "measured"}
 
 
+def paged_block_candidates(*, pps: int, page_size: int, n_kv_heads: int,
+                           head_dim: int, rows: int,
+                           dtype_bytes: int) -> List[int]:
+    """The ``block_pages`` tiles ``tune_paged_attention`` may pick: 1, 2,
+    4, 8 and the whole row (``pps``), where they divide ``pps`` and the
+    kernel's VMEM footprint at ``rows`` query rows per KV head fits
+    ``SCOPED_VMEM_BYTES``."""
+    from repro.kernels.paged_attention import kernel as pa_kernel
+
+    bps = sorted({bp for bp in (1, 2, 4, 8, pps)
+                  if 1 <= bp <= pps and pps % bp == 0})
+    footprint = {bp: pa_kernel.vmem_bytes(
+        block_pages=bp, page_size=page_size, n_kv_heads=n_kv_heads,
+        head_dim=head_dim, rows=rows, dtype_bytes=dtype_bytes)
+        for bp in bps}
+    bps = [bp for bp in bps if footprint[bp] <= SCOPED_VMEM_BYTES]
+    if not bps:
+        raise ValueError(
+            f"no block_pages candidate fits {SCOPED_VMEM_BYTES} B of VMEM: "
+            f"footprints {footprint}")
+    return bps
+
+
 def tune_paged_attention(*, n_slots: int, max_len: int, page_size: int,
                          n_kv_heads: int, n_q_heads: int, head_dim: int,
                          dtype: str, impl: Optional[str] = None,
@@ -171,7 +194,6 @@ def tune_paged_attention(*, n_slots: int, max_len: int, page_size: int,
     """
     import jax
     import jax.numpy as jnp
-    from repro.kernels.paged_attention import kernel as pa_kernel
     from repro.kernels.paged_attention import ops as pa_ops
 
     pps = max_len // page_size
@@ -193,17 +215,9 @@ def tune_paged_attention(*, n_slots: int, max_len: int, page_size: int,
     idx = jnp.arange(n_slots * pps, dtype=jnp.int32).reshape(n_slots, pps)
     positions = jnp.full((n_slots, 1), max_len - 1, jnp.int32)
     valid = jnp.full((n_slots,), max_len, jnp.int32)
-    bps = sorted({bp for bp in (1, 2, 4, 8, pps)
-                  if 1 <= bp <= pps and pps % bp == 0})
-    footprint = {bp: pa_kernel.vmem_bytes(
-        block_pages=bp, page_size=page_size, n_kv_heads=n_kv_heads,
-        head_dim=head_dim, rows=group * max_q_len,
-        dtype_bytes=jdt.itemsize) for bp in bps}
-    bps = [bp for bp in bps if footprint[bp] <= SCOPED_VMEM_BYTES]
-    if not bps:
-        raise ValueError(
-            f"no block_pages candidate fits {SCOPED_VMEM_BYTES} B of VMEM: "
-            f"footprints {footprint}")
+    bps = paged_block_candidates(
+        pps=pps, page_size=page_size, n_kv_heads=n_kv_heads,
+        head_dim=head_dim, rows=group * max_q_len, dtype_bytes=jdt.itemsize)
     candidates = {
         f"bp{bp}": (functools.partial(
             pa_ops.paged_attention, page_size=page_size, block_pages=bp,

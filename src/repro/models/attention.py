@@ -490,10 +490,12 @@ def _paged_attention_with_cache(q, k, v, ps, *, positions, kv_valid_len,
 
     Under a sharding context the kernel runs inside ``shard_map`` (Mosaic
     kernels cannot be partitioned automatically): slots split over the
-    cache's "batch" axes and heads over its "kv_heads" axis, and each
-    shard walks its own rows with the row-local identity page map — the
-    engine's page layout is the identity, so global row b's pages are
-    local row b's pages in the shard that holds b."""
+    cache's "batch" axes and heads over its "kv_heads" axis.  The page
+    map splits with the slots and each shard rebases its rows' global
+    pool page ids onto its own pool view: the shard holding slot rows
+    ``[i*B, (i+1)*B)`` holds pool pages ``[i*B*pps, (i+1)*B*pps)``.  A
+    row's pages must live on its own shard, as ``PagedKVCache(n_shards)``
+    budgets pages per slot block."""
     from repro.kernels.paged_attention import ops as pa_ops
     from repro.parallel import axes as paxes
 
@@ -523,12 +525,21 @@ def _paged_attention_with_cache(q, k, v, ps, *, positions, kv_valid_len,
     # query heads are KV-head-major (head n*G + g), so splitting them over
     # the KV-head axis keeps every group on its KV head's shard
     heads = P(bax, None, hax, None)
-    return shard_map(
-        lambda q, k, v, pos, kv: attend(q, k, v, pos, kv, None),
-        mesh=paxes.current_mesh(),
-        in_specs=(heads, heads, heads, P(bax, None), P(bax)),
-        out_specs=heads, check=False,
-    )(q, k, v, positions, kv_valid_len)
+    args = (q, k, v, positions, kv_valid_len)
+    in_specs = (heads, heads, heads, P(bax, None), P(bax))
+    if ps.page_idx is not None:
+        args += (ps.page_idx,)
+        in_specs += (P(bax, None),)
+
+    def local(q, k, v, positions, kv_valid_len, page_idx=None):
+        if page_idx is not None and bax is not None:
+            B, S_cache = k.shape[:2]
+            page_idx = page_idx - (jax.lax.axis_index(bax)
+                                   * (B * (S_cache // ps.page_size)))
+        return attend(q, k, v, positions, kv_valid_len, page_idx)
+
+    return shard_map(local, mesh=paxes.current_mesh(), in_specs=in_specs,
+                     out_specs=heads, check=False)(*args)
 
 
 def _attn_decode_spkv(params, q, k, v, cfg, *, positions, cache, axis,

@@ -3,7 +3,8 @@
 The paged flash-decode kernel at granite-3-2b's served widths (8 slots,
 32/8 heads, head_dim 64, 16-token pages, 1024-token rows, bf16) is
 compiled, not run, for a v5e described by ``topologies``: decode
-(Sq=1, block_pages 1 and 8), the prefill chunk (Sq=8) and the
+(Sq=1, block_pages 1, 8 and the whole 64-page row), the prefill chunk
+(Sq=8, block_pages 8 and 64) and the
 speculative verify width (Sq=5, spec_k=4), plus the ``shard_map``-wrapped
 call the sharded engine makes on a 2x2 mesh.  Each compiled program must
 hold the kernel (``tpu_custom_call``).
@@ -48,7 +49,8 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("sq,block_pages", [(1, 1), (1, 8), (8, 8), (5, 8)])
+@pytest.mark.parametrize("sq,block_pages", [(1, 1), (1, 8), (1, 64), (8, 8),
+                                            (8, 64), (5, 8)])
 def test_paged_kernel_compiles_for_v5e(topo, sq, block_pages):
     import jax
     import jax.numpy as jnp
@@ -87,14 +89,16 @@ def test_sharded_paged_attention_compiles_on_2x2(topo, monkeypatch):
     rules = rules_for(get_config("granite-3-2b"), mesh)
     heads = NamedSharding(mesh, P("data", None, "model", None))
     rows = NamedSharding(mesh, P("data"))
+    row_cols = NamedSharding(mesh, P("data", None))
     cache = _sds((B, MAX_LEN, NKV, H), jnp.bfloat16, heads)
     args = (_sds((B, 1, NQ, H), jnp.bfloat16, heads), cache, cache,
-            _sds((B, 1), jnp.int32, NamedSharding(mesh, P("data", None))),
-            _sds((B,), jnp.int32, rows))
-    ps = attention.PagedDecodeState(page_idx=None, page_size=PAGE,
-                                    block_pages=8, impl="pallas")
+            _sds((B, 1), jnp.int32, row_cols), _sds((B,), jnp.int32, rows),
+            _sds((B, MAX_LEN // PAGE), jnp.int32, row_cols))
 
-    def step(q, k, v, positions, kv_valid):
+    def step(q, k, v, positions, kv_valid, page_idx):
+        # the engine's decode step: its page map split with the slots
+        ps = attention.PagedDecodeState(page_idx=page_idx, page_size=PAGE,
+                                        block_pages=8, impl="pallas")
         return attention._paged_attention_with_cache(
             q, k, v, ps, positions=positions, kv_valid_len=kv_valid,
             softcap=0.0)

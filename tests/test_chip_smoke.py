@@ -34,11 +34,12 @@ def test_device_check_refuses_cpu():
 
 def test_kernel_phase_reduced():
     cs = _chip_smoke()
-    # served GQA ratio (32/8) at narrow heads; 8 pages per row so the
-    # identity-layout block of 8 pages is exercised
-    err = cs.kernel_phase(batch=8, n_q_heads=8, n_kv_heads=2, head_dim=32,
-                          page_size=16, max_len=128)
+    # served GQA ratio (32/8) at narrow heads; 16 pages per row so the
+    # 8-page tile and the whole-row tile are both candidates
+    err, tiles = cs.kernel_phase(batch=8, n_q_heads=8, n_kv_heads=2,
+                                 head_dim=32, page_size=16, max_len=256)
     assert 0.0 <= err <= cs.KERNEL_TOL
+    assert tiles == [1, 2, 4, 8, 16]
 
 
 def test_serve_phase_reduced():

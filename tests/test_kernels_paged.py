@@ -275,3 +275,64 @@ def test_autotune_sweeps_only_tiles_that_fit_vmem(tmp_path, monkeypatch):
     with pytest.raises(ValueError, match="no block_pages candidate fits"):
         autotune.tune_paged_attention(
             cache_path=tmp_path / "tune.json", max_q_len=8, **_TUNE)
+
+
+# ---------------------------------------------------------------------------
+# sharded call: the page map splits with the slots and is rebased per shard
+# ---------------------------------------------------------------------------
+_SHARDED = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax, jax.numpy as jnp
+from repro.configs import reduced_config
+from repro.launch.mesh import AxisType, make_mesh
+from repro.models import attention
+from repro.parallel import axes as paxes
+from repro.parallel.sharding import rules_for
+
+B, NQ, NKV, H, PAGE, PPS = 4, 8, 2, 32, 8, 4
+ks = jax.random.split(jax.random.key(0), 3)
+q = jax.random.normal(ks[0], (B, 1, NQ, H), jnp.float32)
+k = jax.random.normal(ks[1], (B, PPS * PAGE, NKV, H), jnp.float32)
+v = jax.random.normal(ks[2], (B, PPS * PAGE, NKV, H), jnp.float32)
+# two slot rows per "data" shard; each row's pages are permuted within
+# its own shard's block of the pool
+rng = np.random.default_rng(0)
+idx = np.concatenate([s * 2 * PPS + rng.permutation(2 * PPS)
+                      for s in range(2)]).reshape(B, PPS).astype(np.int32)
+valid = np.array([0, 1, PAGE + 3, PPS * PAGE], np.int32)
+pos = np.maximum(valid - 1, 0)[:, None].astype(np.int32)
+ps = attention.PagedDecodeState(page_idx=jnp.asarray(idx), page_size=PAGE,
+                                block_pages=2, impl="pallas")
+
+
+def step(q, k, v, positions, kv_valid):
+    return attention._paged_attention_with_cache(
+        q, k, v, ps, positions=positions, kv_valid_len=kv_valid, softcap=0.0)
+
+
+want = jax.jit(step)(q, k, v, pos, valid)
+mesh = make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+cfg = reduced_config("granite-3-2b", n_heads=NQ, n_kv_heads=NKV)
+# a fresh function: jit's trace cache does not key on the sharding context
+with paxes.sharding_ctx(mesh, rules_for(cfg, mesh)):
+    got = jax.jit(lambda *a: step(*a))(q, k, v, pos, valid)
+assert len(got.sharding.device_set) == 4, got.sharding
+np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+print("SHARDED_PAGE_MAP_OK")
+"""
+
+
+def test_sharded_call_follows_the_page_map():
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _SHARDED], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "SHARDED_PAGE_MAP_OK" in out.stdout, (
+        out.stdout[-2000:] + out.stderr[-4000:])
