@@ -20,13 +20,8 @@ happen as untimed per-repeat setup — only the drain is timed) and the
 artifact reports the **median** wall/tok-per-s (plus every raw wall) —
 trust orderings and medians, never a single number.
 
-Each engine row also carries the analytic work executed (engine-stats
-``model_flops``/``model_bytes`` from core/costmodel via the engines'
-StepCostModel) and the derived ``roofline_utilization`` — the modeled
-bound time divided by the measured wall (``repro.perf.report.
-roofline_fraction``) — so per-family speedups are roofline-attributable,
-not just tokens/s.  Rows land in benchmarks/results/serve_bench.json in
-the canonical Report schema.
+Rows land in benchmarks/results/serve_bench.json in the canonical Report
+schema.
 
 The **shared-prefix scenario** (always appended on the lm run; run at
 tiny shapes under ``REPRO_BENCH_SMOKE=1``) serves a workload whose
@@ -42,9 +37,9 @@ The **paged-kernel scenario** (appended on the lm run and on the CI
 smoke) races the fused paged flash-decode attention kernel (engine
 default) against the dense XLA gather-then-attend decode
 (``paged_kernel=False``) on the high-variance mix, both with
-``analyze=True``: rows carry ``speedup_vs_xla`` and
-``roofline_utilization``; the Report meta's ``paged`` block carries
-each contender's compiled-program trace-lint verdict, the
+``analyze=True``: rows carry ``speedup_vs_xla``; the Report meta's
+``paged`` block carries each contender's compiled-program trace-lint
+verdict, the
 expected-findings contract (baseline decode must show ``hot-gather``,
 paged decode must not), and the autotuned ``block_pages`` pick from
 ``benchmarks/results/autotune_cache.json`` (``--retune`` re-measures).
@@ -52,9 +47,8 @@ paged decode must not), and the autotuned ``block_pages`` pick from
 The **sharded scenario** (``--sharded``; its own
 ``serve_bench_sharded.json`` artifact) runs the same workload through
 mesh-sharded continuous engines at 1 / 2 / 4 slot shards as equal
-interleaved contenders — tok/s and roofline_utilization per shard
-count, ``speedup_vs_1shard``, and each engine's resolved layout
-(rules + forced-replication decisions from ``parallel.sharding``) in
+interleaved contenders — tok/s per shard count,
+``speedup_vs_1shard``, and each engine's resolved layout (rules + forced-replication decisions from ``parallel.sharding``) in
 the Report meta.  Shard counts needing more devices than the host
 exposes are skipped with a note (fake devices with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``); ``--sp-kv``
@@ -67,7 +61,7 @@ calibrated closed-loop capacity (plus a fixed-trace replay contender),
 driven through ``repro.serve.OpenLoopFrontend``'s virtual clock.  Rows
 carry the schema-validated ``latency`` block — TTFT/TBT/E2E
 p50/p90/p99, queue depth over time, and goodput under a derived
-TTFT+TBT SLO — next to the usual throughput and roofline columns.
+TTFT+TBT SLO — next to the usual throughput columns.
 
 The shared-prefix baseline engine builds with ``analyze=True``, so the
 Report meta's ``analysis`` block records the ``repro.analysis.trace``
@@ -93,7 +87,6 @@ from repro.models import build_model
 from repro.models.decode_state import stub_context
 from repro.perf.measure import measure as perf_measure
 from repro.perf.measure import measure_group
-from repro.perf.report import roofline_fraction
 from repro.serve import (SLO, ContinuousBatchingEngine, OpenLoopFrontend,
                          StaticBatchEngine)
 from repro.serve.arrivals import (poisson_arrivals, synthetic_requests,
@@ -187,9 +180,8 @@ def _workload(rng, n, p_band, g_band, vocab):
 
 
 def _static_pass(engine, reqs, slots, pad_to, extra=None):
-    """One full static pass; returns (generated, model_flops, model_bytes).
+    """One full static pass; returns the tokens generated.
     Wall timing happens in the caller via repro.perf.measure."""
-    f0, b0 = engine.stats.model_flops, engine.stats.model_bytes
     generated = 0
     for w0 in range(0, len(reqs), slots):
         wave = reqs[w0:w0 + slots]
@@ -203,8 +195,7 @@ def _static_pass(engine, reqs, slots, pad_to, extra=None):
                               extra=extra)
         jax.block_until_ready(out)
         generated += sum(g for _, g in reqs[w0:w0 + slots])
-    return generated, engine.stats.model_flops - f0, \
-        engine.stats.model_bytes - b0
+    return generated
 
 
 def _run_pair(model, params, reqs, slots, max_len, *,
@@ -246,27 +237,19 @@ def _run_pair(model, params, reqs, slots, max_len, *,
         interleave_with={"continuous": (cont.run, (), _cont_setup)})
     mc = m.interleaved["continuous"]
 
-    generated, st_flops, st_bytes = m.result     # per-pass deltas
+    generated = m.result                         # per pass
     ct_summary = cont.stats.summary()            # last pass (reset per rep)
     st = {"tok_per_s": generated / m.median_s,
           "wall_s_median": m.median_s,
           "wall_s_all": [round(w, 4) for w in m.all_s],
-          "generated_tokens": generated,
-          "model_flops": st_flops, "model_bytes": st_bytes,
-          "roofline_utilization": roofline_fraction(
-              st_flops, st_bytes, m.median_s)}
+          "generated_tokens": generated}
     ct = {"tok_per_s": ct_summary["generated_tokens"] / mc.median_s,
           "wall_s_median": mc.median_s,
           "wall_s_all": [round(w, 4) for w in mc.all_s],
           "generated_tokens": ct_summary["generated_tokens"],
           "step_ms_p50": ct_summary["step_ms_p50"],
           "step_ms_p95": ct_summary["step_ms_p95"],
-          "mean_occupancy": ct_summary["mean_occupancy"],
-          "model_flops": ct_summary["model_flops"],
-          "model_bytes": ct_summary["model_bytes"],
-          "roofline_utilization": roofline_fraction(
-              ct_summary["model_flops"], ct_summary["model_bytes"],
-              mc.median_s)}
+          "mean_occupancy": ct_summary["mean_occupancy"]}
     return st, ct
 
 
@@ -331,11 +314,7 @@ def _prefix_rows(cfg, model, params, sc: Dict, family: str = "lm"
             "generated_tokens": s["generated_tokens"],
             "prefix_hit_tokens": s["prefix_hit_tokens"],
             "prefix_hit_rate": s["prefix_hit_rate"],
-            "speedup_vs_nocache": base / m.median_s,
-            "model_flops": s["model_flops"],
-            "model_bytes": s["model_bytes"],
-            "roofline_utilization": roofline_fraction(
-                s["model_flops"], s["model_bytes"], m.median_s)})
+            "speedup_vs_nocache": base / m.median_s})
     return rows, analysis
 
 
@@ -394,11 +373,7 @@ def _paged_rows(cfg, model, params, sc: Dict, family: str = "lm", *,
             "wall_s_median": m.median_s,
             "wall_s_all": [round(w, 4) for w in m.all_s],
             "generated_tokens": s["generated_tokens"],
-            "speedup_vs_xla": base / m.median_s,
-            "model_flops": s["model_flops"],
-            "model_bytes": s["model_bytes"],
-            "roofline_utilization": roofline_fraction(
-                s["model_flops"], s["model_bytes"], m.median_s)})
+            "speedup_vs_xla": base / m.median_s})
     meta = {
         "engines": {name: eng.analysis_meta
                     for name, eng in engines.items()},
@@ -499,11 +474,7 @@ def _open_loop_rows(cfg, model, params, sc: Dict, family: str = "lm"
             "tbt_p99_s": lat["tbt_s"]["p99"],
             "slo_attainment": lat["slo"]["attainment"],
             "goodput_tok_s": lat["goodput_tok_s"],
-            "latency": lat,
-            "model_flops": s["model_flops"],
-            "model_bytes": s["model_bytes"],
-            "roofline_utilization": roofline_fraction(
-                s["model_flops"], s["model_bytes"], m.median_s)})
+            "latency": lat})
     meta = {
         "capacity_req_s": capacity_req_s,
         "closed_loop_wall_s": mcap.median_s,
@@ -586,11 +557,7 @@ def _spec_rows(cfg, model, params, sc: Dict, family: str = "lm"
                 "accept_rate": s["accept_rate"],
                 "drafted_tokens": s["drafted_tokens"],
                 "accepted_draft_tokens": s["accepted_draft_tokens"],
-                "speedup_vs_nonspec": base / m.median_s,
-                "model_flops": s["model_flops"],
-                "model_bytes": s["model_bytes"],
-                "roofline_utilization": roofline_fraction(
-                    s["model_flops"], s["model_bytes"], m.median_s)})
+                "speedup_vs_nonspec": base / m.median_s})
         meta["accept_rate"][f"{family}/{mix}"] = (
             engines["spec"].stats.summary()["accept_rate"])
     return rows, meta
@@ -664,10 +631,6 @@ def _sharded_rows(cfg, model, params, sc: Dict, family: str,
             "wall_s_median": m.median_s,
             "wall_s_all": [round(w, 4) for w in m.all_s],
             "generated_tokens": s["generated_tokens"],
-            "model_flops": s["model_flops"],
-            "model_bytes": s["model_bytes"],
-            "roofline_utilization": roofline_fraction(
-                s["model_flops"], s["model_bytes"], m.median_s),
             "speedup_vs_1shard": (base / m.median_s
                                   if base is not None else 1.0)})
         if eng.sharding_meta is not None:
@@ -838,9 +801,8 @@ def run(measure: bool = True,
             "sharded serving: slot shards over the mesh (continuous "
             "engine, median of interleaved repeats)", rows,
             ["family", "shards", "generated_tokens", "tok_per_s",
-             "speedup_vs_1shard", "roofline_utilization"],
-            widths={"family": 7, "speedup_vs_1shard": 18,
-                    "roofline_utilization": 21})
+             "speedup_vs_1shard"],
+            widths={"family": 7, "speedup_vs_1shard": 18})
         print("-> host-CPU walls over faked devices measure sharding "
               "overhead, not speedup — on real multi-chip hardware the "
               "slot shards decode in parallel; Report meta records each "
@@ -904,13 +866,8 @@ def run(measure: bool = True,
             "serving throughput: continuous batching vs static (reduced, "
             "median of interleaved repeats)", classic,
             ["family", "mix", "engine", "generated_tokens", "tok_per_s",
-             "speedup_vs_static", "mean_occupancy", "roofline_utilization"],
-            widths={"family": 7, "mix": 14, "engine": 11,
-                    "roofline_utilization": 21})
-        print("-> roofline_utilization = modeled bound time (costmodel "
-              "flops/bytes vs the TPU-v5e ceiling) / measured host wall; "
-              "absolute values are small on this host — compare across "
-              "families and engines, not against 1.0.")
+             "speedup_vs_static", "mean_occupancy"],
+            widths={"family": 7, "mix": 14, "engine": 11})
     if prefix:
         common.print_table(
             "shared-prefix workload: prefix cache on vs off (continuous "
@@ -926,10 +883,8 @@ def run(measure: bool = True,
         common.print_table(
             "paged flash-decode kernel vs XLA gather decode (continuous "
             "engine, median of interleaved repeats)", paged,
-            ["kernel", "generated_tokens", "tok_per_s", "speedup_vs_xla",
-             "roofline_utilization"],
-            widths={"kernel": 18, "speedup_vs_xla": 15,
-                    "roofline_utilization": 21})
+            ["kernel", "generated_tokens", "tok_per_s", "speedup_vs_xla"],
+            widths={"kernel": 18, "speedup_vs_xla": 15})
         print("-> both contenders decode the same page table; the paged "
               "kernel walks the page-index array inside the attention "
               "kernel (no per-step KV gather, embed via one-hot matmul) "

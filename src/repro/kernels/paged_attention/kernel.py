@@ -176,6 +176,7 @@ def paged_flash_decode(qg, k_pages, v_pages, page_idx, pos0, kv_valid, *,
             jax.ShapeDtypeStruct((B, NKV, GS, LANE), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_attention",
     )(page_idx.astype(jnp.int32), pos0.astype(jnp.int32),
       kv_valid.astype(jnp.int32), qg, k_pages, v_pages)
     return acc, m[..., 0], l[..., 0]

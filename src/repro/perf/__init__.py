@@ -42,6 +42,5 @@ from repro.perf.measure import Measurement, now  # noqa: F401
 from repro.perf.report import (  # noqa: F401
     Report,
     make_report,
-    roofline_fraction,
     validate,
 )

@@ -47,23 +47,6 @@ def hw_meta(hw: HWSpec = TPU_V5E) -> Dict[str, Any]:
             "hbm_bw": hw.hbm_bw, "ici_bw": hw.ici_bw}
 
 
-def roofline_fraction(flops: float, hbm_bytes: float, wall_s: float,
-                      hw: HWSpec = TPU_V5E) -> float:
-    """Fraction of the modeled roofline a measured run achieved.
-
-    ``max(flops/peak, bytes/bw)`` is the modeled bound time for the work;
-    dividing by the measured wall gives "how close to the modeled ceiling
-    this run came" (1.0 = at the roofline).  When the wall is a host-CPU
-    measurement against the TPU model the absolute value is small — trust
-    ratios across configurations, not the absolute number, exactly like
-    every other model-vs-host column in this repo.
-    """
-    if wall_s <= 0:
-        return 0.0
-    t_bound = max(flops / hw.peak_flops_bf16, hbm_bytes / hw.hbm_bw)
-    return t_bound / wall_s
-
-
 @dataclasses.dataclass
 class Report:
     benchmark: str

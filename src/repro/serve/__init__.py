@@ -203,6 +203,15 @@ ranking, batched multi-row prefill chunks amortizing per-chunk
 dispatch, a learned/draft-model drafter behind the NGramDrafter
 interface, and an HTTP/streaming layer over the frontend.
 
+**Tracing** (serve/trace.py): ``ContinuousBatchingEngine.step`` writes
+``jax.profiler`` spans around each step (``serve_step``, with the step
+index as ``step_num``) and its phases (plan, admit, decode, prefill,
+commit; ``serve.flush`` around each result readback), always on and on
+the profiler's clock; after each step that ran a plan,
+``engine.last_event`` holds its ``StepEvent`` — rows run, tokens
+committed per request, admissions, finishes, preemptions, and the device
+array to wait on for the step's tokens.
+
 ``StaticBatchEngine`` remains the run-to-completion baseline used by the
 per-family temperature-0 parity tests and benchmarks/serve_bench.py;
 ``serve/sampling.py`` holds the greedy/temperature sampling shared by
@@ -250,3 +259,4 @@ from repro.serve.slo import (  # noqa: F401
     latency_summary,
     queue_depth_stats,
 )
+from repro.serve.trace import StepEvent  # noqa: F401

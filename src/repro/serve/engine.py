@@ -50,6 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.shapes import ShapeSpec
 from repro.core import costmodel
@@ -59,6 +60,7 @@ from repro.parallel import axes as paxes
 from repro.parallel.sharding import layout_report, rules_for
 from repro.perf.measure import now
 from repro.serve import sampling  # noqa: F401  (submodule import, no cycle)
+from repro.serve import trace
 from repro.serve.cache import PagedKVCache
 from repro.serve.scheduler import Request, Scheduler, StepPlan
 
@@ -105,14 +107,13 @@ class StepRecord:
 
 
 class StepCostModel:
-    """Analytic per-step FLOPs/bytes (core/costmodel) for engine stats.
+    """Analytic per-step FLOPs/bytes (core/costmodel) behind
+    ``ContinuousBatchingEngine.modeled_step_time``, the open-loop
+    frontend's deterministic model clock.
 
     Decode rows are costed at a representative mid-stream cache length
     (``max_len // 2``); prefill tokens at the per-token average of a full
-    ``max_len`` prefill.  These are *model* numbers (the calibrated
-    analytic implementation cost, not a counter) — they make serving
-    throughput roofline-attributable: benchmarks/serve_bench divides the
-    modeled bound time by the measured wall per family.
+    ``max_len`` prefill.  These are *model* numbers, never a measurement.
     """
 
     def __init__(self, cfg, max_len: int):
@@ -149,12 +150,6 @@ class EngineStats:
     steps: List[StepRecord] = dataclasses.field(default_factory=list)
     generated_tokens: int = 0
     wall_s: float = 0.0
-    # analytic (costmodel) work executed this run — the serve half of the
-    # repro.perf measurement surface: wall times come from perf.measure /
-    # per-step now() brackets, work comes from the model, and
-    # benchmarks/serve_bench derives roofline-relative utilization
-    model_flops: float = 0.0
-    model_bytes: float = 0.0
     # prompt tokens whose prefill was skipped via the prefix cache
     # (mirrors Scheduler.prefix_hit_tokens)
     prefix_hit_tokens: int = 0
@@ -176,9 +171,6 @@ class EngineStats:
             return {"steps": 0, "generated_tokens": 0, "tok_per_s": 0.0,
                     "step_ms_p50": 0.0, "step_ms_p95": 0.0,
                     "mean_occupancy": 0.0, "mean_page_utilization": 0.0,
-                    "model_flops": self.model_flops,
-                    "model_bytes": self.model_bytes,
-                    "model_tflops_per_s": 0.0,
                     "prefix_hit_tokens": self.prefix_hit_tokens,
                     "prefix_hit_rate": 0.0,
                     "drafted_tokens": self.drafted_tokens,
@@ -203,10 +195,6 @@ class EngineStats:
                 [s.occupancy for s in self.steps])),
             "mean_page_utilization": float(np.mean(
                 [s.page_utilization for s in self.steps])),
-            "model_flops": self.model_flops,
-            "model_bytes": self.model_bytes,
-            "model_tflops_per_s": (self.model_flops / self.wall_s / 1e12
-                                   if self.wall_s else 0.0),
             # fraction of all prompt tokens served from the prefix cache
             # instead of being prefilled
             "prefix_hit_tokens": self.prefix_hit_tokens,
@@ -492,6 +480,9 @@ class ContinuousBatchingEngine:
         self.last_plan: Optional[StepPlan] = None
         self.last_sampled_rids: List[tuple] = []   # [(slot, rid)]
         self.last_admitted_rids: List[int] = []    # rids first-scheduled
+        # the public record of the last step that ran a plan
+        # (serve/trace.py; None after an iteration that ran nothing)
+        self.last_event: Optional[trace.StepEvent] = None
         # opt-in build-time trace lint: compile the decode/prefill step
         # fns ahead of the first request and run repro.analysis.trace's
         # rules (hot gathers, predication density, counter-blind scans,
@@ -836,9 +827,7 @@ class ContinuousBatchingEngine:
         self._seen_discarded = 0
         self.stats = EngineStats()
         self._results = {}
-        self.last_plan = None
-        self.last_sampled_rids = []
-        self.last_admitted_rids = []
+        self._no_step()
 
     def submit(self, prompt: Sequence[int], max_new_tokens: int, *,
                temperature: float = 0.0,
@@ -969,17 +958,57 @@ class ContinuousBatchingEngine:
         self.stats.accepted_draft_tokens += accepted_draft
 
     def step(self) -> bool:
-        """Run one engine iteration; False when no work remains."""
-        plan = (self.sched.next_plan(self._step_idx,
-                                     drafts=self._propose_drafts())
-                if self.spec_decode
-                else self.sched.next_plan(self._step_idx))
-        if plan is None:
-            self.last_plan = None
-            self.last_sampled_rids = []
-            self.last_admitted_rids = []
+        """Run one engine iteration; False when no work remains.
+
+        Each phase runs inside its profiler span (serve/trace.py), and a
+        step that ran a plan leaves its ``StepEvent`` in ``last_event``."""
+        if not self.sched.has_work():
+            self._no_step()
+            return False
+        with StepTraceAnnotation(trace.SERVE_STEP) as span:
+            with TraceAnnotation(trace.PLAN):
+                plan = (self.sched.next_plan(self._step_idx,
+                                             drafts=self._propose_drafts())
+                        if self.spec_decode
+                        else self.sched.next_plan(self._step_idx))
+            if plan is None:
+                self._no_step()
+                return self.sched.has_work()
+            span.set_metadata(step_num=self._step_idx)
+            t0 = now()
+            if plan.reset_mask.any():
+                with TraceAnnotation(trace.ADMIT):
+                    self._admit_slots(plan)
+            step_idx = np.int32(self._step_idx)
+            n_acc_dev = acc_dev = None
+            if plan.n_decode:
+                with TraceAnnotation(trace.DECODE):
+                    n_acc_dev, acc_dev = self._dispatch_decode(plan, step_idx)
+            for pf in plan.prefills:
+                with TraceAnnotation(trace.PREFILL):
+                    (self._prev_sampled, self.cache,
+                     self._out_buf) = self._prefill_fn(
+                        self.params, self.cache, self._out_buf,
+                        self._prev_sampled, np.int32(pf.slot), pf.tokens,
+                        pf.positions, pf.n_valid, np.float32(pf.temperature),
+                        np.int32(self._slot_row[pf.slot]),
+                        np.int32(pf.out_idx), step_idx, pf.temperature > 0)
+            with TraceAnnotation(trace.COMMIT):
+                self._commit(plan, n_acc_dev, acc_dev, t0)
+            if self.checker is not None:
+                self.checker.check_step()
             return self.sched.has_work()
-        t0 = now()
+
+    def _no_step(self) -> None:
+        """Records of an iteration that ran nothing."""
+        self.last_plan = None
+        self.last_event = None
+        self.last_sampled_rids = []
+        self.last_admitted_rids = []
+
+    def _admit_slots(self, plan: StepPlan) -> None:
+        """Give every slot entering this step a fresh output row and a
+        clean cache row (reset, prefix copy, context install)."""
         for slot in np.nonzero(plan.reset_mask)[0]:
             # a request enters this slot: give it a fresh output row.  A
             # still-mapped old row can only be a preemption orphan —
@@ -991,70 +1020,69 @@ class ContinuousBatchingEngine:
             if not self._free_rows:
                 self._flush_results()
             self._slot_row[slot] = self._free_rows.pop()
-        if plan.reset_mask.any():
-            # three-phase (re-)admission: zero the cold slots, then copy
-            # cached prefixes from their donor rows (prefix-hit slots are
-            # NOT zeroed first — the copy overwrites/zeros every token-
-            # addressable leaf itself, and a donor may be the same slot),
-            # then install per-request read-only context.  The scheduler
-            # guarantees no donor row is claimed by this same plan, so
-            # zeroing before copying can never destroy a donor.
-            zero_mask = plan.reset_mask.copy()
-            prefix_installs = []
-            for slot in np.nonzero(plan.reset_mask)[0]:
-                req = self.sched.active.get(int(slot))
-                if req is not None and req.prefix_len > 0:
-                    zero_mask[slot] = False
-                    prefix_installs.append((int(slot), int(req.prefix_src),
-                                            int(req.prefix_len)))
-            if zero_mask.any():
-                self.cache = self._reset_fn(self.cache, zero_mask)
-            for dst, src, n_tok in prefix_installs:
-                self.cache = self._prefix_fn(self.cache, np.int32(src),
-                                             np.int32(dst), np.int32(n_tok))
-            for slot in np.nonzero(plan.reset_mask)[0]:
-                # install the request's read-only context into the row
-                # (cross K/V projection; the audio adapter also runs the
-                # encoder here, once) — after any prefix copy, so the
-                # context always reflects THIS request
-                req = self.sched.active.get(int(slot))
-                if req is not None and req.extra:
-                    self.cache = self._install_fn(
-                        self.params, self.cache, np.int32(slot), req.extra)
-        step_idx = np.int32(self._step_idx)
-        n_acc_dev = acc_dev = None
-        if plan.n_decode:
-            any_temp = bool((plan.temperatures > 0).any())
-            decode_args = (
-                self.params, self.cache, self._out_buf, self._prev_sampled,
-                plan.tokens, plan.token_src, plan.positions, plan.n_valid,
-                plan.temperatures, self._slot_row.copy(), plan.out_idx,
-                step_idx, any_temp)
-            if self.paged_kernel:
-                decode_args = decode_args + (self._page_idx,)
-            if self.spec_decode and not (plan.n_valid > 1).any():
-                # no drafts in flight this step: run the plain
-                # single-token program (byte-identical to the spec-off
-                # step) instead of the wide verify forward
-                plain_args = (decode_args[:4]
-                              + (plan.tokens[:, :1], plan.token_src,
-                                 plan.positions[:, :1])
-                              + decode_args[7:])
-                (self._prev_sampled, self.cache,
-                 self._out_buf) = self._plain_decode_fn(*plain_args)
-            elif self.spec_decode:
-                (self._prev_sampled, self.cache, self._out_buf,
-                 n_acc_dev, acc_dev) = self._decode_fn(*decode_args)
-            else:
-                (self._prev_sampled, self.cache,
-                 self._out_buf) = self._decode_fn(*decode_args)
-        for pf in plan.prefills:
-            self._prev_sampled, self.cache, self._out_buf = self._prefill_fn(
-                self.params, self.cache, self._out_buf, self._prev_sampled,
-                np.int32(pf.slot), pf.tokens, pf.positions, pf.n_valid,
-                np.float32(pf.temperature),
-                np.int32(self._slot_row[pf.slot]), np.int32(pf.out_idx),
-                step_idx, pf.temperature > 0)
+        # three-phase (re-)admission: zero the cold slots, then copy
+        # cached prefixes from their donor rows (prefix-hit slots are
+        # NOT zeroed first — the copy overwrites/zeros every token-
+        # addressable leaf itself, and a donor may be the same slot),
+        # then install per-request read-only context.  The scheduler
+        # guarantees no donor row is claimed by this same plan, so
+        # zeroing before copying can never destroy a donor.
+        zero_mask = plan.reset_mask.copy()
+        prefix_installs = []
+        for slot in np.nonzero(plan.reset_mask)[0]:
+            req = self.sched.active.get(int(slot))
+            if req is not None and req.prefix_len > 0:
+                zero_mask[slot] = False
+                prefix_installs.append((int(slot), int(req.prefix_src),
+                                        int(req.prefix_len)))
+        if zero_mask.any():
+            self.cache = self._reset_fn(self.cache, zero_mask)
+        for dst, src, n_tok in prefix_installs:
+            self.cache = self._prefix_fn(self.cache, np.int32(src),
+                                         np.int32(dst), np.int32(n_tok))
+        for slot in np.nonzero(plan.reset_mask)[0]:
+            # install the request's read-only context into the row
+            # (cross K/V projection; the audio adapter also runs the
+            # encoder here, once) — after any prefix copy, so the
+            # context always reflects THIS request
+            req = self.sched.active.get(int(slot))
+            if req is not None and req.extra:
+                self.cache = self._install_fn(
+                    self.params, self.cache, np.int32(slot), req.extra)
+
+    def _dispatch_decode(self, plan: StepPlan, step_idx):
+        """Dispatch the batched decode program; returns the speculative
+        verify outputs ``(n_accept, accepted)`` on device, or Nones."""
+        any_temp = bool((plan.temperatures > 0).any())
+        decode_args = (
+            self.params, self.cache, self._out_buf, self._prev_sampled,
+            plan.tokens, plan.token_src, plan.positions, plan.n_valid,
+            plan.temperatures, self._slot_row.copy(), plan.out_idx,
+            step_idx, any_temp)
+        if self.paged_kernel:
+            decode_args = decode_args + (self._page_idx,)
+        if self.spec_decode and not (plan.n_valid > 1).any():
+            # no drafts in flight this step: run the plain
+            # single-token program (byte-identical to the spec-off
+            # step) instead of the wide verify forward
+            plain_args = (decode_args[:4]
+                          + (plan.tokens[:, :1], plan.token_src,
+                             plan.positions[:, :1])
+                          + decode_args[7:])
+            (self._prev_sampled, self.cache,
+             self._out_buf) = self._plain_decode_fn(*plain_args)
+        elif self.spec_decode:
+            (self._prev_sampled, self.cache, self._out_buf,
+             n_acc_dev, acc_dev) = self._decode_fn(*decode_args)
+            return n_acc_dev, acc_dev
+        else:
+            (self._prev_sampled, self.cache,
+             self._out_buf) = self._decode_fn(*decode_args)
+        return None, None
+
+    def _commit(self, plan: StepPlan, n_acc_dev, acc_dev, t0: float) -> None:
+        """Commit the step's samples, feed back its wall, count it, and
+        record its ``StepEvent``."""
         # frontend event capture: which requests sampled a token this
         # step and which were first scheduled (admitted into a reset
         # slot), recorded pre-commit while the slot -> rid map is live.
@@ -1099,9 +1127,6 @@ class ContinuousBatchingEngine:
                 self._spec_feedback(plan, accepted, row_reqs)
         else:
             done = self.sched.commit(plan, sampled, self._step_idx)
-        fl, by = self._cost.step_cost(plan.n_decode, plan.n_prefill_tokens)
-        self.stats.model_flops += fl
-        self.stats.model_bytes += by
         for req in done:
             # tokens stay on device; materialized at the next flush point.
             # Row ownership moves from the slot to the pending map so the
@@ -1125,29 +1150,40 @@ class ContinuousBatchingEngine:
         # away (victim re-prefills from token 0) come back off the total
         discarded = self.sched.discarded_tokens - self._seen_discarded
         self._seen_discarded = self.sched.discarded_tokens
-        committed = (sum(self.sched.last_commit_counts.values())
+        counts = self.sched.last_commit_counts
+        committed = (sum(counts.values())
                      if self.spec_decode else len(plan.sample_slots))
         self.stats.generated_tokens += committed - discarded
         self.stats.prefix_hit_tokens = self.sched.prefix_hit_tokens
         self.stats.wall_s += dt
+        self.last_event = trace.StepEvent(
+            step=self._step_idx, n_decode=plan.n_decode,
+            decode_pos=tuple(plan.positions[plan.n_valid > 0, 0].tolist()),
+            prefills=tuple((int(p.positions[0, 0]), int(p.n_valid[0]),
+                            bool(p.completes_prompt))
+                           for p in plan.prefills),
+            sampled=tuple((rid, int(counts.get(slot, 1)))
+                          for slot, rid in self.last_sampled_rids),
+            admitted=tuple(self.last_admitted_rids),
+            finished=tuple(req.rid for req in done),
+            preempted=self.sched.take_preempted(),
+            ready=self._out_buf)
         self._step_idx += 1
-        if self.checker is not None:
-            self.checker.check_step()
-        return self.sched.has_work()
 
     def _flush_results(self) -> None:
         """Materialize finished requests' tokens (one buffer transfer)
         and recycle their output rows."""
         if not self._pending:
             return
-        buf = np.asarray(self._out_buf)
-        for req in self._pending:
-            row = self._pending_rows.pop(req.rid)
-            toks = buf[row, :req.n_generated].copy()
-            req.generated = toks.tolist()
-            self._results[req.rid] = toks
-            self._free_rows.append(row)
-        self._pending = []
+        with TraceAnnotation(trace.FLUSH):
+            buf = np.asarray(self._out_buf)
+            for req in self._pending:
+                row = self._pending_rows.pop(req.rid)
+                toks = buf[row, :req.n_generated].copy()
+                req.generated = toks.tolist()
+                self._results[req.rid] = toks
+                self._free_rows.append(row)
+            self._pending = []
 
     def run(self, max_steps: Optional[int] = None) -> Dict[int, np.ndarray]:
         """Drain the queue; returns {rid: generated tokens}."""
@@ -1237,9 +1273,8 @@ class StaticBatchEngine:
         self.prefill_fn = jax.jit(make_prefill_step(model))
         self.decode_fn = jax.jit(make_serve_step(
             model, sample_temperature=sample_temperature))
-        self._cost = StepCostModel(model.cfg, max_len)
-        # work accounting only (generated_tokens + model flops/bytes):
-        # the static engine is timed externally, so no per-step walls
+        # token accounting only: the static engine is timed externally,
+        # so no per-step walls
         self.stats = EngineStats()
 
     def generate(self, prompt_tokens, n_steps: int, extra=None):
@@ -1255,9 +1290,5 @@ class StaticBatchEngine:
             nxt, cache = self.decode_fn(self.params, cache, nxt[:, None],
                                         pos, extra)
             out.append(nxt)
-        fl, by = self._cost.step_cost(0, B * S)              # prefill
-        dfl, dby = self._cost.step_cost(B, 0)                # one decode step
-        self.stats.model_flops += fl + (n_steps - 1) * dfl
-        self.stats.model_bytes += by + (n_steps - 1) * dby
         self.stats.generated_tokens += B * n_steps
         return jnp.stack(out, axis=1)                      # (B, n_steps)

@@ -100,9 +100,9 @@ class OpenLoopFrontend:
         res.summary(slo=SLO(ttft_s=0.5, tbt_s=0.1))
 
     The frontend owns no engine state: it submits, steps, and reads the
-    engine's per-step records (``last_plan`` / ``last_sampled_rids`` /
-    ``last_admitted_rids``); ``engine.reset()`` between runs reuses the
-    compiled step functions.
+    engine's public per-step record (``last_event``, a
+    ``serve.trace.StepEvent``); ``engine.reset()`` between runs reuses
+    the compiled step functions.
     """
 
     def __init__(self, engine, *, clock: str = "wall"):
@@ -114,20 +114,22 @@ class OpenLoopFrontend:
     # -- event recording -------------------------------------------------
     def _record_step(self, t: float, events: Dict[int, RequestEvents],
                      live: Dict[int, Any]) -> None:
-        """Fold one executed step's engine records into the event map.
+        """Fold the engine's last ``StepEvent`` into the event map.
         Ordering matters: preemption truncation first (discarded tokens
         leave ``token_times_s``), then first-schedule marks, then this
         step's kept tokens, then finishes."""
-        eng = self.engine
+        step = self.engine.last_event
         # recompute-style preemption throws away a victim's sampled
         # tokens; the event record must not keep their timestamps (TBT /
         # TTFT describe what a client would actually have streamed)
-        for rid, req in live.items():
+        for rid in step.preempted:
+            req = live.get(rid)
+            if req is None:
+                continue
             ev = events[rid]
-            if req.n_preemptions > ev.n_preemptions:
-                ev.n_preemptions = req.n_preemptions
-                del ev.token_times_s[req.n_generated:]
-        for rid in eng.last_admitted_rids:
+            ev.n_preemptions = req.n_preemptions
+            del ev.token_times_s[req.n_generated:]
+        for rid in step.admitted:
             ev = events.get(rid)
             if ev is None:        # pre-queued outside this frontend run
                 continue
@@ -136,8 +138,7 @@ class OpenLoopFrontend:
             req = live.get(rid)
             if req is not None:
                 ev.prefix_len = max(ev.prefix_len, req.prefix_len)
-        counts = eng.sched.last_commit_counts
-        for slot, rid in eng.last_sampled_rids:
+        for rid, c in step.sampled:
             ev = events.get(rid)
             req = live.get(rid)
             if ev is None or req is None:
@@ -146,16 +147,17 @@ class OpenLoopFrontend:
             # share this step's completion instant, producing c - 1 zero
             # TBT gaps (the multi-token event contract — see serve/slo).
             # Without speculation c == 1 and this is the classic append.
-            c = int(counts.get(slot, 1))
-            # belt-and-braces against stale pre-preemption timestamps:
+            # Belt-and-braces against stale pre-preemption timestamps:
             # this step committed tokens n_generated-c+1 .. n_generated
             # (commit already ran), so exactly n_generated-c earlier
             # times stay
             del ev.token_times_s[max(0, req.n_generated - c):]
             ev.token_times_s.extend([t] * c)
             ev.n_generated = req.n_generated
-        for rid in [r for r, req in live.items() if req.finish_reason]:
-            req = live.pop(rid)
+        for rid in step.finished:
+            req = live.pop(rid, None)
+            if req is None:
+                continue
             ev = events[rid]
             ev.finish_s = t
             ev.finish_reason = req.finish_reason
@@ -210,14 +212,14 @@ class OpenLoopFrontend:
                     dt = now() - t0
                 else:
                     eng.step()
-                    plan = eng.last_plan
-                    dt = (eng.modeled_step_time(plan.n_decode,
-                                                plan.n_prefill_tokens)
-                          if plan is not None else 0.0)
-                    if plan is not None:
+                    step = eng.last_event
+                    dt = (eng.modeled_step_time(step.n_decode,
+                                                step.n_prefill_tokens)
+                          if step is not None else 0.0)
+                    if step is not None:
                         eng.sched.note_step_wall(
-                            dt, plan.n_decode + plan.n_prefill_tokens)
-                if eng.last_plan is None:
+                            dt, step.n_decode + step.n_prefill_tokens)
+                if eng.last_event is None:
                     # work queued but nothing placeable; submitting more
                     # requests cannot free pages, so this is the same
                     # dead state engine.run() guards against

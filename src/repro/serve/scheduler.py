@@ -206,6 +206,8 @@ class Scheduler:
         # tokens sampled by victims and thrown away by recompute-style
         # preemption (lets the engine report *useful* throughput)
         self.discarded_tokens = 0
+        # rids preempted since the engine last took them (take_preempted)
+        self._preempted: List[int] = []
         # prompt tokens whose prefill was skipped via the prefix cache
         self.prefix_hit_tokens = 0
         # slots admitted while composing the current plan: their device
@@ -380,9 +382,16 @@ class Scheduler:
             req.n_generated = 0
             req.generated = []
             req.n_preemptions += 1
+            self._preempted.append(req.rid)
             self.queue.appendleft(req)
             return slot
         return None
+
+    def take_preempted(self) -> tuple:
+        """Rids preempted since the last call, in order, and forget them."""
+        out = tuple(self._preempted)
+        self._preempted.clear()
+        return out
 
     # -- stall-free chunk sizing ----------------------------------------
     def note_step_wall(self, wall_s: float, n_tokens: int) -> None:
