@@ -415,11 +415,50 @@ def attn_prefill(params, x, cfg, *, positions, cache, kv_chunk=1024,
     out = chunked_attention(q, k, v, causal=True,
                             softcap=cfg.attn_logit_softcap,
                             kv_chunk=kv_chunk, block_causal=block_causal)
-    S_cache = cache["k"].shape[1]
-    kc = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
-    vc = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-    new_cache = {"k": kc, "v": vc, "pos": cache["pos"] + S}
-    return _out_proj(params, out, cfg), new_cache
+    k_all, v_all, layer = _cache_stack(cache)
+    start = (layer, 0, 0, 0, 0)
+    kc = jax.lax.dynamic_update_slice(k_all, k[None].astype(k_all.dtype), start)
+    vc = jax.lax.dynamic_update_slice(v_all, v[None].astype(v_all.dtype), start)
+    return _out_proj(params, out, cfg), _with_stack(cache, kc, vc,
+                                                    cache["pos"] + S)
+
+
+def _cache_stack(cache):
+    """``(k, v, layer)``: a cache's K/V as a layer stack, and the layer
+    this call reads and writes.
+
+    Inside the layer scan a dense/moe cache arrives as a *layer view* of
+    the stack the scan carries (``blocks.run_stack``): ``k``/``v`` hold
+    every layer, (L, B, S_cache, NKV, H), and ``layer`` is this one's
+    index.  Any other cache holds one layer's (B, S_cache, NKV, H) and
+    is a stack of one."""
+    if "layer" in cache:
+        return cache["k"], cache["v"], cache["layer"]
+    return cache["k"][None], cache["v"][None], 0
+
+
+def _with_stack(cache, k, v, pos):
+    """The updated cache in ``cache``'s own form (see ``_cache_stack``)."""
+    if "layer" in cache:
+        return dict(cache, k=k, v=v, pos=pos)
+    return {"k": k[0], "v": v[0], "pos": pos}
+
+
+def _write_tokens(stack, new, layer, idx):
+    """Scatter ``new`` (B, S, NKV, H) into ``stack`` (L, B, S_cache, NKV,
+    H) at ``[layer, b, idx[b, s]]``: one scatter over the whole stack,
+    in place when the stack is donated or carried.  Indices past the
+    cache (``idx == S_cache``) are dropped."""
+    B, S = idx.shape
+    where = jnp.stack([
+        jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B, S)),
+        jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, S)),
+        idx.astype(jnp.int32)], axis=-1)                      # (B, S, 3)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(2, 3), inserted_window_dims=(0, 1, 2),
+        scatter_dims_to_operand_dims=(0, 1, 2))
+    return jax.lax.scatter(stack, where, new.astype(stack.dtype), dnums,
+                           mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
 
 
 def attn_decode(params, x, cfg, *, positions, cache, n_valid=None):
@@ -434,10 +473,16 @@ def attn_decode(params, x, cfg, *, positions, cache, n_valid=None):
     advances by ``n_valid`` instead of S.  ``None`` keeps the classic
     all-rows-full behavior.
 
+    ``cache`` is one layer's cache or, inside the dense/moe layer scan, a
+    layer view of the stacked cache (``_cache_stack``): the step's K/V go
+    into the stack with one scatter and the attention reads them there,
+    so no layer's K/V is sliced out of the stack or written back.
+
     When the active sharding rules map the cache length ("kv_seq") to a
     mesh axis, the sequence-parallel flash-decoding path runs instead:
     each shard attends over its cache slice and the partial online-softmax
     states combine with one tiny pmax/psum — the cache is never gathered.
+    It takes the layer's slice of a stack and writes it back.
     """
     from repro.parallel.axes import rule_axes
 
@@ -447,96 +492,114 @@ def attn_decode(params, x, cfg, *, positions, cache, n_valid=None):
     if cfg.rope_theta > 0:
         q = layers.apply_rope(q, positions, cfg.rope_theta)
         k = layers.apply_rope(k, positions, cfg.rope_theta)
+    k_all, v_all, layer = _cache_stack(cache)
+    pos = cache["pos"]                                    # (B,)
     kv_axes = rule_axes("kv_seq")
     if kv_axes:
-        return _attn_decode_spkv(params, q, k, v, cfg,
-                                 positions=positions, cache=cache,
-                                 axis=kv_axes[0], n_valid=n_valid)
+        out, lc = _attn_decode_spkv(
+            params, q, k, v, cfg, positions=positions,
+            cache={"k": jax.lax.dynamic_index_in_dim(k_all, layer, 0, False),
+                   "v": jax.lax.dynamic_index_in_dim(v_all, layer, 0, False),
+                   "pos": pos},
+            axis=kv_axes[0], n_valid=n_valid)
+        kc = jax.lax.dynamic_update_index_in_dim(k_all, lc["k"], layer, 0)
+        vc = jax.lax.dynamic_update_index_in_dim(v_all, lc["v"], layer, 0)
+        return out, _with_stack(cache, kc, vc, lc["pos"])
     q, k, v = _constrain_qkv(q, k, v)
-    pos = cache["pos"]                                    # (B,)
-    S_cache = cache["k"].shape[1]
+    S_cache = k_all.shape[2]
     idx = pos[:, None] + jnp.arange(S)[None]              # (B,S)
     step = jnp.full((B,), S, jnp.int32) if n_valid is None else n_valid
     if n_valid is not None:
         # padding columns scatter out of bounds -> dropped
         idx = jnp.where(jnp.arange(S)[None] < n_valid[:, None], idx, S_cache)
-    kc = jax.vmap(lambda c, u, i: c.at[i].set(u, mode="drop"))(
-        cache["k"], k.astype(cache["k"].dtype), idx)
-    vc = jax.vmap(lambda c, u, i: c.at[i].set(u, mode="drop"))(
-        cache["v"], v.astype(cache["v"].dtype), idx)
-    new_cache = {"k": kc, "v": vc, "pos": pos + step}
+    kc = _write_tokens(k_all, k, layer, idx)
+    vc = _write_tokens(v_all, v, layer, idx)
+    new_cache = _with_stack(cache, kc, vc, pos + step)
     ps = paged_state()
     pageable = (ps is not None and S_cache % ps.page_size == 0
                 and (ps.page_idx is None or ps.page_idx.shape
                      == (B, S_cache // ps.page_size)))
     if pageable:
         out = _paged_attention_with_cache(
-            q, kc, vc, ps, positions=positions, kv_valid_len=pos + step,
-            softcap=cfg.attn_logit_softcap)
+            q, kc, vc, ps, layer=layer, positions=positions,
+            kv_valid_len=pos + step, softcap=cfg.attn_logit_softcap)
     else:
         out = _full_attention_with_cache(
-            q, kc, vc, positions=positions, kv_valid_len=pos + step,
+            q, jax.lax.dynamic_index_in_dim(kc, layer, 0, False),
+            jax.lax.dynamic_index_in_dim(vc, layer, 0, False),
+            positions=positions, kv_valid_len=pos + step,
             softcap=cfg.attn_logit_softcap)
     return _out_proj(params, out, cfg), new_cache
 
 
-def _paged_attention_with_cache(q, k, v, ps, *, positions, kv_valid_len,
-                                softcap):
-    """Fused paged decode: the cache (B, S_cache, NKV, H) is *viewed* as
-    a page pool (B*pages, page_size, NKV, H) — a reshape, not a gather —
-    and kernels/paged_attention streams pages by page-id with the ragged
-    mask folded in.  Clears the trace-lint ``hot-gather`` finding the
-    dense ``_full_attention_with_cache`` path triggers.
+def _paged_attention_with_cache(q, k, v, ps, *, layer, positions,
+                                kv_valid_len, softcap):
+    """Fused paged decode over layer ``layer`` of the stacked cache: the
+    stack (L, B, S_cache, NKV, H) is *viewed* as a page pool
+    (L*B*pages, page_size, NKV, H) — a reshape, not a gather or a copy —
+    and kernels/paged_attention streams the layer's pages by page id,
+    ``page_idx + layer*B*pages``, with the ragged mask folded in.
+    Clears the trace-lint ``hot-gather`` finding the dense
+    ``_full_attention_with_cache`` path triggers.  The XLA twin
+    (``impl="xla"``) is specialised to one layer's identity-laid pool,
+    so it reads the layer out of the stack.
 
     Under a sharding context the kernel runs inside ``shard_map`` (Mosaic
     kernels cannot be partitioned automatically): slots split over the
-    cache's "batch" axes and heads over its "kv_heads" axis.  The page
-    map splits with the slots and each shard rebases its rows' global
-    pool page ids onto its own pool view: the shard holding slot rows
-    ``[i*B, (i+1)*B)`` holds pool pages ``[i*B*pps, (i+1)*B*pps)``.  A
-    row's pages must live on its own shard, as ``PagedKVCache(n_shards)``
-    budgets pages per slot block."""
+    cache's "batch" axes and heads over its "kv_heads" axis; the layer
+    axis stays whole.  The page map splits with the slots and each shard
+    rebases its rows' global page ids onto its own pool view: the shard
+    holding slot rows ``[i*B, (i+1)*B)`` holds each layer's pages
+    ``[i*B*pps, (i+1)*B*pps)``.  A row's pages must live on its own
+    shard, as ``PagedKVCache(n_shards)`` budgets pages per slot block."""
     from repro.kernels.paged_attention import ops as pa_ops
     from repro.parallel import axes as paxes
 
-    def attend(q, k, v, positions, kv_valid_len, page_idx):
-        B, S_cache, NKV, H = k.shape
+    layer = jnp.asarray(layer, jnp.int32)
+
+    def attend(q, k, v, positions, kv_valid_len, layer, page_idx):
+        L, B, S_cache, NKV, H = k.shape
         pps = S_cache // ps.page_size
-        k_pages = k.reshape(B * pps, ps.page_size, NKV, H)
-        v_pages = v.reshape(B * pps, ps.page_size, NKV, H)
         if page_idx is None:
             # row-local identity map (engine prefill rows run batch=1)
             page_idx = jnp.arange(B * pps, dtype=jnp.int32).reshape(B, pps)
+        if pa_ops.resolve_impl(ps.impl) == "xla":
+            k = jax.lax.dynamic_index_in_dim(k, layer, 0)
+            v = jax.lax.dynamic_index_in_dim(v, layer, 0)
+            L, layer = 1, 0
+        k_pages = k.reshape(L * B * pps, ps.page_size, NKV, H)
+        v_pages = v.reshape(L * B * pps, ps.page_size, NKV, H)
         return pa_ops.paged_attention(
-            q, k_pages, v_pages, page_idx, positions, kv_valid_len,
-            page_size=ps.page_size, softcap=softcap,
+            q, k_pages, v_pages, page_idx + layer * (B * pps), positions,
+            kv_valid_len, page_size=ps.page_size, softcap=softcap,
             block_pages=ps.block_pages, impl=ps.impl)
 
     if not paxes.active():
-        return attend(q, k, v, positions, kv_valid_len, ps.page_idx)
+        return attend(q, k, v, positions, kv_valid_len, layer, ps.page_idx)
 
     from jax.sharding import PartitionSpec as P
     from repro.core.compat import shard_map
 
-    spec = paxes.resolve_spec(("batch", None, "kv_heads", None), k.shape,
-                              record=False)
-    spec = tuple(spec) + (None,) * (4 - len(spec))
-    bax, hax = spec[0], spec[2]
+    spec = paxes.resolve_spec((None, "batch", None, "kv_heads", None),
+                              k.shape, record=False)
+    spec = tuple(spec) + (None,) * (5 - len(spec))
+    bax, hax = spec[1], spec[3]
     # query heads are KV-head-major (head n*G + g), so splitting them over
     # the KV-head axis keeps every group on its KV head's shard
     heads = P(bax, None, hax, None)
-    args = (q, k, v, positions, kv_valid_len)
-    in_specs = (heads, heads, heads, P(bax, None), P(bax))
+    stack = P(None, bax, None, hax, None)
+    args = (q, k, v, positions, kv_valid_len, layer)
+    in_specs = (heads, stack, stack, P(bax, None), P(bax), P())
     if ps.page_idx is not None:
         args += (ps.page_idx,)
         in_specs += (P(bax, None),)
 
-    def local(q, k, v, positions, kv_valid_len, page_idx=None):
+    def local(q, k, v, positions, kv_valid_len, layer, page_idx=None):
         if page_idx is not None and bax is not None:
-            B, S_cache = k.shape[:2]
+            B, S_cache = k.shape[1:3]
             page_idx = page_idx - (jax.lax.axis_index(bax)
                                    * (B * (S_cache // ps.page_size)))
-        return attend(q, k, v, positions, kv_valid_len, page_idx)
+        return attend(q, k, v, positions, kv_valid_len, layer, page_idx)
 
     return shard_map(local, mesh=paxes.current_mesh(), in_specs=in_specs,
                      out_specs=heads, check=False)(*args)
@@ -676,6 +739,12 @@ def init_cache(cfg, batch: int, max_len: int, dtype) -> Dict[str, jax.Array]:
         "v": jnp.zeros((batch, max_len, nkv, h), dtype),
         "pos": jnp.zeros((batch,), jnp.int32),
     }
+
+
+def is_kv_cache(cache) -> bool:
+    """True for an attention KV cache, one layer's or a stack of them:
+    exactly the leaves ``init_cache`` makes."""
+    return isinstance(cache, dict) and set(cache) == {"k", "v", "pos"}
 
 
 def cache_specs(cfg) -> Dict[str, Any]:

@@ -7,7 +7,8 @@ period: Jamba scans 8-layer periods (1 attn : 7 mamba, MoE on odd layers),
 the VLM scans 5-layer periods (4 self-attn + 1 gated cross-attn layer).
 
 Each block body has three modes — train / prefill / decode — selected
-statically; caches ride along as scan xs/ys.
+statically; a stacked attention KV cache rides the scan's carry, other
+caches ride along as scan xs/ys.
 """
 from __future__ import annotations
 
@@ -197,7 +198,30 @@ def run_stack(
     n_steps: int = 0,
     remat: str = "none",
 ) -> Tuple[jax.Array, Optional[Any], jax.Array]:
-    """Scan ``step_fn`` over stacked layer params (+ optional stacked cache)."""
+    """Scan ``step_fn`` over stacked layer params (+ optional stacked cache).
+
+    A stacked attention KV cache (``attention.is_kv_cache``: the dense and
+    moe layout) rides the scan's carry: each step gets a layer view of the
+    whole stack (``attention._cache_stack``), writes its tokens into it in
+    place and attends there, so no layer's K/V is sliced out and stacked
+    back.  Only the per-layer positions go through xs/ys.  Any other cache
+    is scanned as xs/ys, one slice per step."""
+
+    if attention.is_kv_cache(stacked_cache):
+        def kv_body(carry, inp):
+            xc, aux, k, v = carry
+            p, pos, layer = inp
+            xn, c, a = step_fn(xc, p, {"k": k, "v": v, "pos": pos,
+                                       "layer": layer})
+            return (xn, aux + a, c["k"], c["v"]), c["pos"]
+
+        n_layers = stacked_cache["k"].shape[0]
+        (x, aux, k, v), pos = jax.lax.scan(
+            kv_body, (x, jnp.zeros((), jnp.float32), stacked_cache["k"],
+                      stacked_cache["v"]),
+            (stacked_params, stacked_cache["pos"],
+             jnp.arange(n_layers, dtype=jnp.int32)))
+        return x, {"k": k, "v": v, "pos": pos}, aux
 
     from jax.ad_checkpoint import checkpoint_name
 

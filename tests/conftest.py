@@ -21,6 +21,7 @@ SERVE_TEST_MODULES = (
     "test_serve_prefix",
     "test_serve_sharded",
     "test_serve_spec",
+    "test_serve_kv_stack",
     "test_spkv_decode",
     "test_chip_smoke",
 )

@@ -293,8 +293,9 @@ from repro.parallel.sharding import rules_for
 B, NQ, NKV, H, PAGE, PPS = 4, 8, 2, 32, 8, 4
 ks = jax.random.split(jax.random.key(0), 3)
 q = jax.random.normal(ks[0], (B, 1, NQ, H), jnp.float32)
-k = jax.random.normal(ks[1], (B, PPS * PAGE, NKV, H), jnp.float32)
-v = jax.random.normal(ks[2], (B, PPS * PAGE, NKV, H), jnp.float32)
+# a two-layer stacked cache: the call reads layer 1's pages from the stack
+k = jax.random.normal(ks[1], (2, B, PPS * PAGE, NKV, H), jnp.float32)
+v = jax.random.normal(ks[2], (2, B, PPS * PAGE, NKV, H), jnp.float32)
 # two slot rows per "data" shard; each row's pages are permuted within
 # its own shard's block of the pool
 rng = np.random.default_rng(0)
@@ -308,10 +309,18 @@ ps = attention.PagedDecodeState(page_idx=jnp.asarray(idx), page_size=PAGE,
 
 def step(q, k, v, positions, kv_valid):
     return attention._paged_attention_with_cache(
-        q, k, v, ps, positions=positions, kv_valid_len=kv_valid, softcap=0.0)
+        q, k, v, ps, layer=1, positions=positions, kv_valid_len=kv_valid,
+        softcap=0.0)
 
 
-want = jax.jit(step)(q, k, v, pos, valid)
+# the kernel over layer 1's own pool
+from repro.kernels.paged_attention import ops as pa_ops
+want = pa_ops.paged_attention(
+    q, k[1].reshape(B * PPS, PAGE, NKV, H),
+    v[1].reshape(B * PPS, PAGE, NKV, H), jnp.asarray(idx), pos, valid,
+    page_size=PAGE, block_pages=2, impl="pallas")
+np.testing.assert_allclose(np.asarray(jax.jit(step)(q, k, v, pos, valid)),
+                           np.asarray(want), atol=1e-5)
 mesh = make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 cfg = reduced_config("granite-3-2b", n_heads=NQ, n_kv_heads=NKV)
 # a fresh function: jit's trace cache does not key on the sharding context
